@@ -44,6 +44,7 @@ def main() -> None:
     bm, bn = fused.tile("M"), fused.tile("F")
     bk = fused.tile("K")
     y = gemm_act(x, w, act="gelu", block_m=bm, block_n=bn, block_k=bk,
+                 vmem_limit_bytes=target.vmem_limit_bytes,
                  interpret=jax.default_backend() != "tpu")
     y_ref = ref.gemm_act(x, w, act="gelu")
     err = float(jnp.abs(y - y_ref).max())

@@ -43,6 +43,12 @@ _C_REQUALIFIED = obs.counter(
 _C_XLA_FALLBACK = obs.counter(
     "ftl_xla_fallback_total",
     "runtime rebinds that landed on an XLA reference executor", ("kind",))
+# which executor each stage actually ran: one count per run call, i.e.
+# per trace under jax.jit — the ground truth behind resolved_executors()
+_C_DISPATCH = obs.counter(
+    "ftl_dispatch_total",
+    "stage executor runs (per trace under jit)",
+    ("phase", "kind", "executor"))
 
 
 def _runtime_ctx(
@@ -115,22 +121,30 @@ def _stage_executor(
     return fb
 
 
-def _bind_target(ex: registry.Executor, target) -> registry.Executor:
+def _counted_run(run, labels: dict[str, str], *args, **kwargs):
+    _C_DISPATCH.labels(**labels).inc()
+    return run(*args, **kwargs)
+
+
+def _bind_target(ex: registry.Executor, plan) -> registry.Executor:
     """Pin the executor to the plan's own target: every run function (the
     Pallas kernels' block-size planning, the scan executors' token-tile
     choice) must price itself against the machine the plan was made for,
-    not whatever the process default happens to be at run time."""
+    not whatever the process default happens to be at run time.  Each
+    run is counted on ``ftl_dispatch_total``."""
+    labels = {"phase": plan.phase, "kind": ex.kind, "executor": ex.name}
     return dataclasses.replace(
         ex,
-        run=functools.partial(ex.run, target=target),
+        run=functools.partial(_counted_run, ex.run, labels,
+                              target=plan.target),
     )
 
 
 def _resolve_gemm(plan, mode, m, dtype) -> registry.Executor:
     if mode == "off":
-        return _bind_target(registry.get("xla_gemm"), plan.target)
+        return _bind_target(registry.get("xla_gemm"), plan)
     ctx = _runtime_ctx(plan, "gemm", plan.schedule, m, dtype)
-    return _bind_target(_stage_executor(plan, "gemm", ctx), plan.target)
+    return _bind_target(_stage_executor(plan, "gemm", ctx), plan)
 
 
 def _resolve_attention(plan, mode, m, dtype) -> registry.Executor:
@@ -139,7 +153,7 @@ def _resolve_attention(plan, mode, m, dtype) -> registry.Executor:
         # the jnp oracle elsewhere — exactly what a 'fused' qualification
         # resolves to
         ctx = _runtime_ctx(plan, "attention", "fused", m, dtype)
-        return _bind_target(registry.find("attention", ctx), plan.target)
+        return _bind_target(registry.find("attention", ctx), plan)
     ctx = _runtime_ctx(
         plan,
         "attention",
@@ -147,7 +161,7 @@ def _resolve_attention(plan, mode, m, dtype) -> registry.Executor:
         m,
         dtype,
     )
-    return _bind_target(_stage_executor(plan, "attention", ctx), plan.target)
+    return _bind_target(_stage_executor(plan, "attention", ctx), plan)
 
 
 def _resolve_mlp(
@@ -170,7 +184,7 @@ def _resolve_mlp(
             d_ff = cfg.moe_d_ff if cfg.is_moe else cfg.d_ff
         if gated is None:
             gated = cfg.mlp_gated
-        return registry.mlp_executor(
+        ex = registry.mlp_executor(
             mode,
             m=m,
             d_model=d_model,
@@ -180,13 +194,15 @@ def _resolve_mlp(
             act=cfg.mlp_act,
             target=plan.target,
         )
+        return _bind_target(ex, plan)
     ctx = _runtime_ctx(plan, "mlp", _sub_schedule(plan, "mlp"), m, dtype)
-    return _bind_target(_stage_executor(plan, "mlp", ctx), plan.target)
+    return _bind_target(_stage_executor(plan, "mlp", ctx), plan)
 
 
 # public names for the per-stage resolvers: the serving path
-# (models.layers.mlp_layer with plan=) dispatches its MLP through the
-# plan's binding exactly as run_block would, without running run_block
+# (models.layers' prefill attention and mlp_layer with plan=) dispatches
+# its stages through the plan's bindings exactly as run_block would,
+# without running run_block
 resolve_mlp = _resolve_mlp
 resolve_attention = _resolve_attention
 resolve_gemm = _resolve_gemm
@@ -216,7 +232,7 @@ def resolved_executors(
     }
 
 
-def _project(ex: registry.Executor, x, p: dict[str, Any]):
+def project(ex: registry.Executor, x, p: dict[str, Any]):
     """One planned projection GEMM (``linear`` routed through a binding)."""
     w = p["w"]
     if ex.backend == "pallas":
@@ -278,9 +294,9 @@ def run_block(
         with obs.span(f"seg:attn:{attn_ex.name}", "exec"):
             ap = params["attn"]
             h = L.norm(params["ln1"], x, cfg.norm)
-            q = L._split_heads(_project(gemm_ex, h, ap["wq"]), nh)
-            k = L._split_heads(_project(gemm_ex, h, ap["wk"]), hk)
-            v = L._split_heads(_project(gemm_ex, h, ap["wv"]), hk)
+            q = L._split_heads(project(gemm_ex, h, ap["wq"]), nh)
+            k = L._split_heads(project(gemm_ex, h, ap["wk"]), hk)
+            v = L._split_heads(project(gemm_ex, h, ap["wv"]), hk)
             if use_rope:
                 q = L.rope(q, positions, cfg.rope_theta)
                 k = L.rope(k, positions, cfg.rope_theta)
@@ -289,7 +305,7 @@ def run_block(
             v = constrain(v.transpose(0, 2, 1, 3), "heads_kv")
             o = attn_ex.run(q, k, v, causal=causal, window=window)
             o = o.transpose(0, 2, 1, 3).reshape(b, s, nh * dh)
-            x = constrain(x + _project(gemm_ex, o, ap["wo"]), "residual")
+            x = constrain(x + project(gemm_ex, o, ap["wo"]), "residual")
 
     if "mlp" in params:
         mp = params["mlp"]

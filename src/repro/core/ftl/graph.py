@@ -246,6 +246,21 @@ def gemm_chain_graph(
 # whole-block capture from a ModelConfig
 # ---------------------------------------------------------------------------
 
+def _block_parts(cfg) -> tuple[bool, int, bool]:
+    """(has attention, MLP hidden width, gated) of ``cfg``'s block."""
+    d_ff = cfg.moe_d_ff if cfg.is_moe else cfg.d_ff
+    return (cfg.block_kind(0) in ("attn", "cross", "local"), d_ff,
+            cfg.mlp_gated)
+
+
+def has_plannable_block(cfg) -> bool:
+    """True when :func:`block_graph` can lower ``cfg`` (it has an
+    attention block or an MLP); pure recurrent stacks have nothing to
+    plan."""
+    has_attn, d_ff, _ = _block_parts(cfg)
+    return has_attn or d_ff > 0
+
+
 def block_graph(
     cfg,
     *,
@@ -270,11 +285,7 @@ def block_graph(
     """
     dt = dtype or cfg.dtype
     d = cfg.d_model
-    if cfg.is_moe:
-        d_ff, gated = cfg.moe_d_ff, cfg.mlp_gated
-    else:
-        d_ff, gated = cfg.d_ff, cfg.mlp_gated
-    has_attn = cfg.block_kind(0) in ("attn", "cross", "local")
+    has_attn, d_ff, gated = _block_parts(cfg)
     has_mlp = d_ff > 0
 
     ops: list[OpNode] = []

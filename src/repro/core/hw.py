@@ -54,6 +54,11 @@ KB = 1 << 10
 MB = 1 << 20
 GB = 1 << 30
 
+# Fast memory the Pallas pipeline machinery (semaphores, DMA descriptors,
+# Mosaic's internal scratch) needs on top of the tiles the planner places:
+# a TPU preset's planning budget is its physical VMEM minus this.
+PALLAS_VMEM_HEADROOM = 32 * MB
+
 
 @dataclasses.dataclass(frozen=True)
 class MemoryLevel:
@@ -191,6 +196,14 @@ class Target:
     def fast_capacity(self) -> int:
         """The tile budget (bytes) — what `vmem_budget` used to be."""
         return self.fast.capacity_bytes
+
+    @property
+    def vmem_limit_bytes(self) -> int:
+        """Scoped fast-memory limit a Pallas kernel planned for this
+        target asks the compiler for: the planner's fast-level budget
+        plus :data:`PALLAS_VMEM_HEADROOM` for the pipeline machinery
+        (tpu_v5e: 96 + 32 MiB, the chip's whole physical VMEM)."""
+        return self.fast.capacity_bytes + PALLAS_VMEM_HEADROOM
 
     @property
     def interconnect(self) -> MemoryLevel | None:
@@ -546,8 +559,9 @@ def _fmt_bytes(n: int) -> str:
 # ---------------------------------------------------------------------------
 
 # TPU v5e class (task-specified constants).  The fast level is the 96 MiB
-# the planner may claim — the physical 128 MiB VMEM minus the headroom the
-# Pallas pipeline machinery / semaphores need.  VMEM is DMA-fed: the
+# the planner may claim — the physical 128 MiB VMEM minus
+# PALLAS_VMEM_HEADROOM, which Target.vmem_limit_bytes adds back as the
+# kernels' scoped-VMEM limit.  VMEM is DMA-fed: the
 # Pallas pipeline double-buffers every streamed tile.  ICI-reachable
 # remote HBM plays the deep-tier role for the roofline's collective term.
 TPU_V5E = Target(
@@ -690,7 +704,10 @@ def _tpu_target(device_kind: str) -> Target:
                 ),
                 flops=flops,
             )
-    return TPU_V5E
+    raise ValueError(
+        f"unknown TPU device_kind {device_kind!r}: no planning target is "
+        f"described for it (known: {[tag for tag, _ in _TPU_GENERATIONS]}); "
+        f"pass a target explicitly or set FTL_TARGET")
 
 
 def detect_target(devices: Sequence | None = None) -> Target:
@@ -698,26 +715,28 @@ def detect_target(devices: Sequence | None = None) -> Target:
 
     TPU hosts map their generation (``device_kind``) to VMEM size / peak
     FLOP/s / HBM bandwidth; CPU hosts get the cache-blocked
-    :data:`CPU_CACHE` preset.  Anything else (GPU, or a host where jax
-    itself is unavailable) falls back to :data:`TPU_V5E` — the repo's
-    serving target — until a dedicated hierarchy lands.  ``devices``
-    is injectable for tests; None reads ``jax.devices()``.
+    :data:`CPU_CACHE` preset.  Any other platform, and a TPU generation
+    the table does not know, raises ``ValueError``: planning a real
+    device against another machine's hierarchy would pick tiles its
+    compiler refuses.  Planner-only uses name their target (``target=``,
+    ``FTL_TARGET``, :func:`set_default_target`).  ``devices`` is
+    injectable for tests; None reads ``jax.devices()``.
     """
     if devices is None:
-        try:
-            import jax
-            devices = jax.devices()
-        except Exception:  # jax missing/uninitializable: planner-only use
-            return TPU_V5E
+        import jax
+        devices = jax.devices()
     if not devices:
-        return TPU_V5E
+        raise ValueError("detect_target needs at least one device")
     dev = devices[0]
     platform = getattr(dev, "platform", "")
     if platform == "tpu":
         return _tpu_target(getattr(dev, "device_kind", ""))
     if platform == "cpu":
         return CPU_CACHE
-    return TPU_V5E
+    raise ValueError(
+        f"no planning target is described for platform {platform!r} "
+        f"({getattr(dev, 'device_kind', '')!r}); pass a target explicitly "
+        f"or set FTL_TARGET")
 
 
 # ---------------------------------------------------------------------------

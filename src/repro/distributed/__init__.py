@@ -1,21 +1,5 @@
 """Distribution layer: sharding rules (FSDP×TP), activation policy,
-gradient compression, pipeline parallelism.
-
-``shard_map`` is re-exported here with a version shim: the pinned
-jax==0.4.37 only ships it as ``jax.experimental.shard_map.shard_map``
-(``jax.shard_map`` landed later), and every shard_map consumer in the
-repo (``pipeline.py``, ``compression.py``'s collective building blocks,
-the tests) must resolve it through this one spot instead of re-deciding
-per call site.
-"""
-import jax
-
-try:  # newer jax: public top-level API
-    shard_map = jax.shard_map
-except AttributeError:  # pinned jax 0.4.37: still experimental
-    from jax.experimental.shard_map import shard_map
-
+gradient compression, pipeline parallelism."""
 from . import act_sharding, compression, pipeline, sharding
 
-__all__ = ["act_sharding", "compression", "pipeline", "sharding",
-           "shard_map"]
+__all__ = ["act_sharding", "compression", "pipeline", "sharding"]

@@ -35,6 +35,21 @@ def use_policy(policy: Policy | None):
         _POLICY.reset(token)
 
 
+def mesh() -> jax.sharding.Mesh | None:
+    """The mesh the code being traced is partitioned over: the installed
+    policy's ``mesh`` attribute, None outside any policy.  Ops XLA cannot
+    partition (the Pallas kernels) run per shard of it; a policy that
+    does not name its mesh leaves them no way to, so that raises."""
+    policy = _POLICY.get()
+    if policy is None:
+        return None
+    m = getattr(policy, "mesh", None)
+    if m is None:
+        raise ValueError("the installed sharding policy has no mesh: "
+                         "per-shard kernels cannot be placed")
+    return m
+
+
 def constrain(x: jax.Array, kind: str) -> jax.Array:
     policy = _POLICY.get()
     if policy is None:

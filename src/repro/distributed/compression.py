@@ -16,8 +16,7 @@ Two entry points:
   inside any step (the error-feedback state lives in the train state).
 * ``compressed_psum`` — shard_map building block doing the actual int8
   ``lax.psum`` over a named axis, for explicit-collective steps.  Wrap
-  it with ``repro.distributed.shard_map`` (the version shim — the
-  pinned jax 0.4.37 has no public ``jax.shard_map``).
+  it with ``jax.shard_map``.
 """
 from __future__ import annotations
 
@@ -72,7 +71,7 @@ def init_error(params: Params) -> Params:
 
 def compressed_psum(x: jax.Array, axis_name: str) -> jax.Array:
     """int8 all-reduce over ``axis_name`` (use inside
-    ``repro.distributed.shard_map``).
+    ``jax.shard_map``).
 
     Quantizes locally, sums int32 (no overflow up to ~2^24 shards), then
     averages the per-shard dequantized values.  Scales are all-gathered

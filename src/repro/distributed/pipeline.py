@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed import shard_map  # version shim (jax 0.4.37)
 
 Params = dict[str, Any]
 
@@ -84,7 +83,7 @@ def pipeline_forward(
         _, out = jax.lax.fori_loop(0, m + n_stages - 1, tick, (zero, out0))
         return jax.lax.psum(out, axis)   # only the last stage wrote
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), staged_params), P(axis)),
         out_specs=P(),

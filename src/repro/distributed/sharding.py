@@ -197,6 +197,7 @@ def make_activation_policy(mesh: Mesh, cfg):
             return x
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
+    policy.mesh = mesh      # act_sharding.mesh(): per-shard Pallas kernels
     return policy
 
 

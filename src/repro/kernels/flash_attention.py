@@ -76,7 +76,8 @@ def _make_kernel(*, causal: bool, window: int | None, scale: float,
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "causal", "window", "q_offset", "block_q", "block_k", "interpret"
+        "causal", "window", "q_offset", "block_q", "block_k",
+        "vmem_limit_bytes", "interpret"
     ),
 )
 def flash_attention(
@@ -89,6 +90,7 @@ def flash_attention(
     q_offset: int = 0,
     block_q: int = 256,
     block_k: int = 256,
+    vmem_limit_bytes: int,
     interpret: bool = False,
 ) -> jax.Array:
     b, hq, tq, dh = q.shape
@@ -131,6 +133,8 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, hq, tq, dh)

@@ -70,7 +70,8 @@ def _make_kernel(act: str, gated: bool, has_b1: bool, has_b2: bool):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("act", "block_m", "block_f", "interpret"),
+    static_argnames=("act", "block_m", "block_f", "vmem_limit_bytes",
+                     "interpret"),
 )
 def fused_mlp(
     x: jax.Array,                 # (M, K)
@@ -83,6 +84,7 @@ def fused_mlp(
     act: str = "gelu",
     block_m: int = 256,
     block_f: int = 512,
+    vmem_limit_bytes: int,
     interpret: bool = False,
 ) -> jax.Array:
     m, k = x.shape
@@ -123,5 +125,7 @@ def fused_mlp(
         out_specs=pl.BlockSpec((block_m, n), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(*args)

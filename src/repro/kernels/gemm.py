@@ -29,7 +29,9 @@ def _gemm_kernel(x_ref, w_ref, o_ref, acc_ref):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_m", "block_n", "block_k", "interpret")
+    jax.jit,
+    static_argnames=("block_m", "block_n", "block_k", "vmem_limit_bytes",
+                     "interpret"),
 )
 def gemm(
     x: jax.Array,            # (M, K)
@@ -38,6 +40,7 @@ def gemm(
     block_m: int = 256,
     block_n: int = 256,
     block_k: int = 512,
+    vmem_limit_bytes: int,
     interpret: bool = False,
 ) -> jax.Array:
     m, k = x.shape
@@ -60,5 +63,7 @@ def gemm(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(x, w)

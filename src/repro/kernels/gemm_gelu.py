@@ -46,7 +46,8 @@ def _make_kernel(act: str, has_bias: bool):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("act", "block_m", "block_n", "block_k", "interpret"),
+    static_argnames=("act", "block_m", "block_n", "block_k",
+                     "vmem_limit_bytes", "interpret"),
 )
 def gemm_act(
     x: jax.Array,              # (M, K)
@@ -57,6 +58,7 @@ def gemm_act(
     block_m: int = 256,
     block_n: int = 256,
     block_k: int = 512,
+    vmem_limit_bytes: int,
     interpret: bool = False,
 ) -> jax.Array:
     m, k = x.shape
@@ -84,5 +86,7 @@ def gemm_act(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(*args)

@@ -3,7 +3,8 @@
 Every op here:
   * asks the FTL solver for block sizes (kernel-policy constraints of the
     specific Pallas dataflow are passed as ``whole_dims``),
-  * runs the Pallas kernel on TPU, or in interpret mode elsewhere,
+  * runs the Pallas kernel on TPU, or in interpret mode elsewhere — in a
+    program partitioned over a mesh, once per shard (``_per_shard``),
   * can be forced onto the jnp reference path (``backend='ref'``) — that is
     the layer-per-layer baseline used across benchmarks.
 
@@ -15,9 +16,12 @@ import functools
 from typing import Literal
 
 import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import ftl
 from repro.core import hw as hwlib
+from repro.distributed import act_sharding, sharding as shd
 
 from . import ref as _ref
 from .flash_attention import flash_attention as _flash
@@ -37,9 +41,32 @@ def _interpret() -> bool:
 def _resolve(backend: Backend) -> str:
     if backend == "auto":
         # Pallas on TPU; the jnp path elsewhere (interpret mode is for
-        # validation, not production CPU execution).
+        # validation, not production CPU execution)
         return "pallas" if jax.default_backend() == "tpu" else "ref"
     return backend
+
+
+def _with_ref_grad(kernel, reference):
+    """``kernel`` with the gradient of ``reference``, its jnp oracle.
+
+    These Pallas kernels have no backward pass, and JAX cannot derive one
+    through a ``pallas_call``, so a train step on the TPU differentiates
+    the reference at the same inputs instead: the kernel runs forward, the
+    oracle is recomputed for the VJP.  The backward therefore costs what
+    the reference costs: for attention it builds the whole (Tq, Tk) score
+    matrix, O(T^2) memory, where the forward kernel streams it."""
+    @jax.custom_vjp
+    def f(*args):
+        return kernel(*args)
+
+    def fwd(*args):
+        return kernel(*args), args
+
+    def bwd(args, g):
+        return jax.vjp(reference, *args)[1](g)
+
+    f.defvjp(fwd, bwd)
+    return f
 
 
 # ---------------------------------------------------------------------------
@@ -131,6 +158,41 @@ def plan_attention_blocks(
 
 
 # ---------------------------------------------------------------------------
+# per-shard placement
+# ---------------------------------------------------------------------------
+
+def _dp(mesh: Mesh, n: int):
+    """The mesh's data axes for a dim of size ``n``, if it divides."""
+    return shd._div(mesh, n, shd.dp_axes(mesh)) or None
+
+
+def _tp(mesh: Mesh, n: int):
+    """The ``model`` axis for a dim of size ``n``, if it divides."""
+    return shd._div(mesh, n, "model") if "model" in mesh.axis_names else None
+
+
+def _per_shard(body, mesh: Mesh, args, in_specs, out_specs):
+    """``body(*args)`` once per shard of ``mesh``.
+
+    XLA cannot partition a Mosaic kernel, so inside a program partitioned
+    over a mesh (``act_sharding.mesh()``) each Pallas kernel runs under
+    ``jax.shard_map`` on the local blocks ``in_specs`` cut out of its
+    operands, and plans its block sizes for those local shapes.  ``None``
+    arguments (absent biases, states) pass through as ``None``."""
+    given = [i for i, a in enumerate(args) if a is not None]
+
+    def local(*xs):
+        full = [None] * len(args)
+        for i, x in zip(given, xs):
+            full[i] = x
+        return body(*full)
+
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=tuple(in_specs[i] for i in given),
+        out_specs=out_specs, check_vma=False)(*(args[i] for i in given))
+
+
+# ---------------------------------------------------------------------------
 # ops
 # ---------------------------------------------------------------------------
 
@@ -138,10 +200,22 @@ def gemm(x, w, *, backend: Backend = "auto",
          target: hwlib.Target | None = None):
     if _resolve(backend) == "ref":
         return _ref.gemm(x, w)
-    bm, bn, bk = plan_gemm_blocks(x.shape[0], x.shape[1], w.shape[1],
-                                  str(x.dtype), None, target)
-    return _gemm(x, w, block_m=bm, block_n=bn, block_k=bk,
-                 interpret=_interpret())
+
+    def body(x, w):
+        bm, bn, bk = plan_gemm_blocks(x.shape[0], x.shape[1], w.shape[1],
+                                      str(x.dtype), None, target)
+        kernel = functools.partial(
+            _gemm, block_m=bm, block_n=bn, block_k=bk,
+            vmem_limit_bytes=_kernel_target(target).vmem_limit_bytes,
+            interpret=_interpret())
+        return _with_ref_grad(kernel, _ref.gemm)(x, w)
+
+    mesh = act_sharding.mesh()
+    if mesh is None:
+        return body(x, w)
+    rows, cols = _dp(mesh, x.shape[0]), _tp(mesh, w.shape[1])
+    return _per_shard(body, mesh, (x, w), (P(rows, None), P(None, cols)),
+                      P(rows, cols))
 
 
 def gemm_act(x, w, b=None, *, act: str = "gelu", backend: Backend = "auto",
@@ -149,10 +223,23 @@ def gemm_act(x, w, b=None, *, act: str = "gelu", backend: Backend = "auto",
     """The paper's benchmark op."""
     if _resolve(backend) == "ref":
         return _ref.gemm_act(x, w, b, act=act)
-    bm, bn, bk = plan_gemm_blocks(x.shape[0], x.shape[1], w.shape[1],
-                                  str(x.dtype), act, target)
-    return _gemm_act(x, w, b, act=act, block_m=bm, block_n=bn, block_k=bk,
-                     interpret=_interpret())
+
+    def body(x, w, b):
+        bm, bn, bk = plan_gemm_blocks(x.shape[0], x.shape[1], w.shape[1],
+                                      str(x.dtype), act, target)
+        kernel = functools.partial(
+            _gemm_act, act=act, block_m=bm, block_n=bn, block_k=bk,
+            vmem_limit_bytes=_kernel_target(target).vmem_limit_bytes,
+            interpret=_interpret())
+        return _with_ref_grad(
+            kernel, functools.partial(_ref.gemm_act, act=act))(x, w, b)
+
+    mesh = act_sharding.mesh()
+    if mesh is None:
+        return body(x, w, b)
+    rows, cols = _dp(mesh, x.shape[0]), _tp(mesh, w.shape[1])
+    return _per_shard(body, mesh, (x, w, b),
+                      (P(rows, None), P(None, cols), P(cols)), P(rows, cols))
 
 
 def fused_mlp(x, w1, w2, wg=None, b1=None, b2=None, *, act: str = "gelu",
@@ -163,10 +250,32 @@ def fused_mlp(x, w1, w2, wg=None, b1=None, b2=None, *, act: str = "gelu",
         return _ref.mlp(x, w1, w2, wg, b1, b2, act=act)
     *lead, m, k = x.shape
     xf = x.reshape(-1, k)
-    bm, bf = plan_mlp_blocks(xf.shape[0], k, w1.shape[1], str(x.dtype),
-                             wg is not None, act, target)
-    y = _fused_mlp(xf, w1, w2, wg, b1, b2, act=act, block_m=bm, block_f=bf,
-                   interpret=_interpret())
+
+    def body(xf, w1, w2, wg, b1, b2):
+        bm, bf = plan_mlp_blocks(xf.shape[0], k, w1.shape[1], str(x.dtype),
+                                 wg is not None, act, target)
+        kernel = functools.partial(
+            _fused_mlp, act=act, block_m=bm, block_f=bf,
+            vmem_limit_bytes=_kernel_target(target).vmem_limit_bytes,
+            interpret=_interpret())
+        return _with_ref_grad(kernel, functools.partial(_ref.mlp, act=act))(
+            xf, w1, w2, wg, b1, b2)
+
+    mesh = act_sharding.mesh()
+    if mesh is None:
+        y = body(xf, w1, w2, wg, b1, b2)
+    else:
+        # d_ff split over 'model': each shard's slice of w2 gives a partial
+        # sum of the output, stacked on a leading axis and added up here
+        # (b2 once, after the sum)
+        rows, f = _dp(mesh, xf.shape[0]), _tp(mesh, w1.shape[1])
+        parts = _per_shard(
+            lambda *a: body(*a, None)[None], mesh, (xf, w1, w2, wg, b1),
+            (P(rows, None), P(None, f), P(f, None), P(None, f), P(f)),
+            P(f, rows, None))
+        y = parts.sum(0, dtype=jnp.float32).astype(x.dtype)
+        if b2 is not None:
+            y = y + b2
     return y.reshape(*lead, m, w2.shape[1])
 
 
@@ -197,16 +306,45 @@ def attention(q, k, v, *, causal: bool = True, window: int | None = None,
                 block_k=max(bk, 1024))
         return _ref.attention(q, k, v, causal=causal, window=window,
                               q_offset=q_offset)
-    bq, bk = plan_attention_blocks(q.shape[2], k.shape[2], q.shape[3],
-                                   str(q.dtype), target)
-    return _flash(q, k, v, causal=causal, window=window, q_offset=q_offset,
-                  block_q=bq, block_k=bk, interpret=_interpret())
+
+    def body(q, k, v):
+        bq, bk = plan_attention_blocks(q.shape[2], k.shape[2], q.shape[3],
+                                       str(q.dtype), target)
+        kernel = functools.partial(
+            _flash, causal=causal, window=window, q_offset=q_offset,
+            block_q=bq, block_k=bk,
+            vmem_limit_bytes=_kernel_target(target).vmem_limit_bytes,
+            interpret=_interpret())
+        reference = functools.partial(_ref.attention, causal=causal,
+                                      window=window, q_offset=q_offset)
+        return _with_ref_grad(kernel, reference)(q, k, v)
+
+    mesh = act_sharding.mesh()
+    if mesh is None:
+        return body(q, k, v)
+    # heads split over 'model' only when the KV heads divide (then the
+    # query heads do too, and each shard keeps whole GQA groups)
+    spec = P(_dp(mesh, q.shape[0]), _tp(mesh, k.shape[1]), None, None)
+    return _per_shard(body, mesh, (q, k, v), (spec,) * 3, spec)
 
 
 def rg_lru(x, a, h0=None, *, backend: Backend = "auto"):
     if _resolve(backend) == "ref":
         return _ref.rg_lru_scan(x, a, h0)
-    return _rg_lru(x, a, h0, interpret=_interpret())
+
+    def body(x, a, h0):
+        kernel = functools.partial(
+            _rg_lru, vmem_limit_bytes=_kernel_target(None).vmem_limit_bytes,
+            interpret=_interpret())
+        return _with_ref_grad(kernel, _ref.rg_lru_scan)(x, a, h0)
+
+    mesh = act_sharding.mesh()
+    if mesh is None:
+        return body(x, a, h0)
+    rows, feats = _dp(mesh, x.shape[0]), _tp(mesh, x.shape[2])
+    seq, state = P(rows, None, feats), P(rows, feats)
+    return _per_shard(body, mesh, (x, a, h0), (seq, seq, state),
+                      (seq, state))
 
 
 def mlstm(q, k, v, i_pre, f_pre, *, backend: Backend = "auto",
@@ -217,4 +355,17 @@ def mlstm(q, k, v, i_pre, f_pre, *, backend: Backend = "auto",
         return _ref.mlstm_scan(q, k, v, i_pre, f_pre, return_state=True)
     if _resolve(backend) == "ref":
         return _ref.mlstm_scan(q, k, v, i_pre, f_pre)
-    return _mlstm(q, k, v, i_pre, f_pre, interpret=_interpret())
+
+    def body(q, k, v, i_pre, f_pre):
+        kernel = functools.partial(
+            _mlstm, vmem_limit_bytes=_kernel_target(None).vmem_limit_bytes,
+            interpret=_interpret())
+        return _with_ref_grad(kernel, _ref.mlstm_scan)(q, k, v, i_pre, f_pre)
+
+    mesh = act_sharding.mesh()
+    if mesh is None:
+        return body(q, k, v, i_pre, f_pre)
+    rows, heads = _dp(mesh, q.shape[0]), _tp(mesh, q.shape[1])
+    seq, gate = P(rows, heads, None, None), P(rows, heads, None)
+    return _per_shard(body, mesh, (q, k, v, i_pre, f_pre),
+                      (seq, seq, seq, gate, gate), seq)

@@ -45,6 +45,7 @@ from repro.configs import get_config
 from repro.core import hw
 from repro.core.ftl import registry as ftl_registry
 from repro.launch import kv_cache as KV
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.train import steps as S
 
@@ -209,7 +210,7 @@ class ServeEngine:
         self.plans.warmup()
         _, self.decode_plan = self.plans.get(1, "decode")
         _, self.block_plan = self.plans.get(self.buckets[-1], "prefill")
-        self._decode_fn = self._build_decode(self.decode_plan)
+        self.decode_fn = self._build_decode(self.decode_plan)
         self._decode_fn_plan = self.decode_plan
         self._prefill_fns: dict[int, Any] = {}
 
@@ -306,7 +307,9 @@ class ServeEngine:
 
         return jax.jit(paged_step)
 
-    def _prefill_fn(self, bucket: int, plan):
+    def prefill_fn(self, bucket: int, plan):
+        """The engine's jitted prefill step for ``bucket`` (built once);
+        ``decode_fn`` is its jitted decode step."""
         fn = self._prefill_fns.get(bucket)
         if fn is None:
             # paged caches splice page-aligned bucket-length caches; the
@@ -351,20 +354,20 @@ class ServeEngine:
         extras = extras or {}
         for b in self.buckets:
             _, plan = self.plans.get(b, "prefill")
-            fn = self._prefill_fn(b, plan)
+            fn = self.prefill_fn(b, plan)
             batch = {"tokens": jnp.zeros((1, b), jnp.int32), **extras}
             fn(self.params, batch, jnp.int32(b - 1))[0].block_until_ready()
         tok = jnp.zeros((self.slots, 1), jnp.int32)
         if self.paged:
             pos = jnp.zeros((self.slots,), jnp.int32)
             zero = jnp.zeros((self.slots,), jnp.int32)
-            out = self._decode_fn(self.params, self.kv.pool,
+            out = self.decode_fn(self.params, self.kv.pool,
                                   self.kv.table_array(), tok, pos, zero,
                                   zero)
         else:
             pos = (jnp.zeros((self.slots,), jnp.int32) if self._vector_pos
                    else jnp.int32(0))
-            out = self._decode_fn(self.params, self.cache, tok, pos)
+            out = self.decode_fn(self.params, self.cache, tok, pos)
         out[0].block_until_ready()
 
     # ------------------------------------------------------------------
@@ -393,12 +396,14 @@ class ServeEngine:
             jax.random.PRNGKey(0), (1, self.max_seq, cfg.d_model)
         ).astype(cfg.dtype)
         positions = jnp.arange(self.max_seq)
-        run = jax.jit(lambda xx: ftl_registry.run_block(
-            self.block_plan, p, xx, positions=positions, window=window))
-        run(x).block_until_ready()              # compile
+        # the layer's weights are an argument, not a closure constant the
+        # compiler would copy into the executable
+        run = jax.jit(lambda pp, xx: ftl_registry.run_block(
+            self.block_plan, pp, xx, positions=positions, window=window))
+        run(p, x).block_until_ready()           # compile
         t0 = time.perf_counter()
         with obslib.span("serve:block_exec", "exec"):
-            y = run(x)
+            y = run(p, x)
             y.block_until_ready()
         dt = time.perf_counter() - t0
         entry = {
@@ -470,7 +475,7 @@ class ServeEngine:
         padded = np.zeros(bucket, np.int32)
         padded[:plen] = req.prompt
         batch = {"tokens": jnp.asarray(padded)[None], **extras}
-        fn = self._prefill_fn(bucket, plan)
+        fn = self.prefill_fn(bucket, plan)
         # bucket padding is on the right; the prompt's real last token
         # sits at plen-1 and decode overwrites the pad KV in place
         with obslib.span(f"serve:prefill:m{bucket}", "serve"):
@@ -532,7 +537,7 @@ class ServeEngine:
         # replan, and gated to zero in bench_serve
         _, plan = self.plans.get(1, "decode")
         if plan is not self._decode_fn_plan:
-            self._decode_fn = self._build_decode(plan)
+            self.decode_fn = self._build_decode(plan)
             self._decode_fn_plan = plan
             self.decode_plan = plan
             self.stats["replans"] += 1
@@ -557,7 +562,7 @@ class ServeEngine:
                     wblk[i], woff[i] = self.kv.write_coords(
                         i, int(self.pos[i]))
                 # dead slots keep (0, 0): the scratch page
-            logits, self.kv.pool = self._decode_fn(
+            logits, self.kv.pool = self.decode_fn(
                 self.params, self.kv.pool, self.kv.table_array(),
                 jnp.asarray(tok), jnp.asarray(self.pos),
                 jnp.asarray(wblk), jnp.asarray(woff))
@@ -568,7 +573,7 @@ class ServeEngine:
                 pos = jnp.int32(int(max(
                     (self.pos[i] for i, r in enumerate(self.active)
                      if r is not None), default=0)))
-            logits, self.cache = self._decode_fn(
+            logits, self.cache = self.decode_fn(
                 self.params, self.cache, jnp.asarray(tok), pos)
 
         nxt = np.asarray(jnp.argmax(logits[:, 0], -1))
@@ -690,6 +695,10 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--target", default=None,
                     help="planning target preset (default: auto-detect)")
+    ap.add_argument("--ftl-mode", default=None,
+                    choices=["off", "fused", "scan", "auto"],
+                    help="executor mode (default: the config's); 'auto' "
+                    "runs the planned bindings, Pallas kernels on TPU")
     ap.add_argument("--block-size", type=int, default=8,
                     help="paged-KV page length in tokens")
     ap.add_argument("--dense-kv", action="store_true",
@@ -719,9 +728,12 @@ def main() -> None:
                  f"({args.trace}); they would silently overwrite each "
                  "other — give them distinct paths")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.ftl_mode:
+        cfg = dataclasses.replace(cfg, ftl_mode=args.ftl_mode)
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
     extras: dict[str, Any] = {}
     if cfg.family == "vlm":

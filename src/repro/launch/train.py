@@ -29,10 +29,11 @@ import jax.numpy as jnp
 
 from repro import obs as obslib
 from repro.configs import get_config
-from repro.core.ftl import InfeasibleError, executor_block
-from repro.core.ftl import registry as ftl_registry
+from repro.core.ftl import executor_block
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
+from repro.models import model as M
 from repro.optim import OptConfig
 from repro.runtime import LoopConfig, TrainLoop
 from repro.runtime.monitor import HeartbeatMonitor
@@ -51,9 +52,10 @@ def build(args):
     # plan (per cfg/m/dtype) and executes every block through
     # registry.run_block, so the schedule logged here is the schedule the
     # train step actually runs.
-    bp = None
-    try:
-        bp = ftl_registry.plan_block(cfg, m=args.seq)
+    bp = M.plan_or_none(cfg, m=args.seq)
+    if bp is None:
+        logging.info("FTL block plan unavailable (layer-per-layer path)")
+    else:
         execs = executor_block.resolved_executors(bp, m=args.seq)
         state = ("executed by every forward block"
                  if cfg.ftl_mode != "off" else
@@ -62,9 +64,6 @@ def build(args):
         logging.info("FTL block plan (m=%d, target=%s, %s):\n%s\n"
                      "  runtime executors: %s",
                      args.seq, bp.target.name, state, bp.summary(), execs)
-    except (ValueError, InfeasibleError) as e:
-        logging.info("FTL block plan unavailable (layer-per-layer path): "
-                     "%s", e)
 
     mesh = None
     in_sh = out_sh = None
@@ -157,6 +156,7 @@ def main() -> None:
         args.obs = True
     if args.obs:
         obslib.enable()
+    enable_compile_cache()
 
     loop = build(args)
     loop.run()

@@ -8,6 +8,7 @@ without a framework dependency.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -151,6 +152,7 @@ def attention_prefill(
     kv_source: jax.Array | None = None,
     use_rope: bool = True,
     pad_to: int | None = None,
+    plan=None,                       # registry.BlockPlan | None
 ) -> tuple[jax.Array, Params]:
     """Full-sequence attention that also returns the decode cache.
 
@@ -159,25 +161,42 @@ def attention_prefill(
     right-pads the cache seq dim so decode steps can append in place
     (decode DUS clamps out-of-range starts — an unpadded cache would
     silently corrupt its last slot).
+
+    ``plan`` (self-attention only) runs the projections and the attention
+    core through the plan's gemm/attention bindings, as ``run_block``
+    does, so serving prefill executes what ``plan_report`` resolves.
     """
     h, hk, dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    q = _split_heads(linear(p["wq"], x), h)
+    if plan is not None:
+        from repro.core.ftl import executor_block  # lazy: no cycle
+        m, dt = x.shape[1], str(x.dtype)
+        gemm_ex = executor_block.resolve_gemm(plan, cfg.ftl_mode, m, dt)
+        attend = executor_block.resolve_attention(
+            plan, cfg.ftl_mode, m, dt).run
+
+        def proj(pp, xx):
+            return executor_block.project(gemm_ex, xx, pp)
+    else:
+        proj = linear
+        attend = functools.partial(
+            ops.attention,
+            backend="ref" if jax.default_backend() != "tpu" else "auto")
+    q = _split_heads(proj(p["wq"], x), h)
     src = x if kv_source is None else kv_source
-    k = _split_heads(linear(p["wk"], src), hk)
-    v = _split_heads(linear(p["wv"], src), hk)
+    k = _split_heads(proj(p["wk"], src), hk)
+    v = _split_heads(proj(p["wv"], src), hk)
     if use_rope and kv_source is None:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
-    o = ops.attention(
+    o = attend(
         constrain(q.transpose(0, 2, 1, 3), "heads_q"),
         constrain(k.transpose(0, 2, 1, 3), "heads_kv"),
         constrain(v.transpose(0, 2, 1, 3), "heads_kv"),
         causal=causal and kv_source is None,
         window=window,
-        backend="ref" if jax.default_backend() != "tpu" else "auto",
     )
     o = o.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[1], h * dh)
-    out = linear(p["wo"], o)
+    out = proj(p["wo"], o)
     s = k.shape[1]
     if window is not None and s >= window:
         tail_k, tail_v = k[:, -window:], v[:, -window:]

@@ -25,13 +25,14 @@ runs under pjit, remat, eval_shape (dry-run) and CPU smoke tests.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import hw
-from repro.core.ftl import InfeasibleError
+from repro.core.ftl import InfeasibleError, graph
 from repro.core.ftl import registry as ftl_registry
 from repro.distributed.act_sharding import constrain
 from repro.models import recurrent
@@ -52,6 +53,8 @@ from repro.models.layers import (
 from repro.models.moe import init_moe, moe_layer
 
 Params = dict[str, Any]
+
+log = logging.getLogger(__name__)
 
 # kinds whose mixer handles its own input norm (recurrent blocks do)
 _SELF_NORMED = {"mlstm", "slstm", "rec"}
@@ -212,15 +215,27 @@ def _scan_layers(cfg, stack: Params, kinds: list[str], x, *, positions, ctx,
     return x, aux
 
 
+def plan_or_none(cfg, **kwargs):
+    """``registry.plan_block(cfg, **kwargs)``, or None where "no plan" is
+    the planner's documented outcome: the config has no plannable block
+    (pure recurrent stacks), or no tile assignment fits the target
+    (``InfeasibleError``, logged).  Any other planning error propagates —
+    a model that should have a plan never silently runs without one."""
+    if not graph.has_plannable_block(cfg):
+        return None
+    try:
+        return ftl_registry.plan_block(cfg, **kwargs)
+    except InfeasibleError as e:
+        log.warning("no FTL plan for %s (%s): %s", cfg.name, kwargs, e)
+        return None
+
+
 @functools.lru_cache(maxsize=256)
 def _block_plan_cached(cfg, m: int, dtype: str, target, autotune):
     if cfg.is_moe or cfg.ftl_mode == "off":
         return None
-    try:
-        return ftl_registry.plan_block(cfg, m=m, dtype=dtype, target=target,
-                                       autotune=autotune)
-    except (ValueError, InfeasibleError):
-        return None
+    return plan_or_none(cfg, m=m, dtype=dtype, target=target,
+                        autotune=autotune)
 
 
 def _block_plan(cfg, m: int, dtype: str, target=None, autotune=None):
@@ -274,11 +289,7 @@ def bucket_m(m: int, buckets: tuple[int, ...] = PREFILL_BUCKETS) -> int:
 
 @functools.lru_cache(maxsize=512)
 def _serve_plan_cached(cfg, m: int, dtype: str, target, phase: str):
-    try:
-        return ftl_registry.plan_block(cfg, m=m, dtype=dtype, target=target,
-                                       phase=phase)
-    except (ValueError, InfeasibleError):
-        return None
+    return plan_or_none(cfg, m=m, dtype=dtype, target=target, phase=phase)
 
 
 ftl_registry.register_plan_cache("model._block_plan_cached",
@@ -343,8 +354,15 @@ def _unembed(cfg, params: Params, x: jax.Array) -> jax.Array:
 # public API — decoder-only families
 # ===========================================================================
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg, key) -> Params:
-    """Full parameter tree (works under jax.eval_shape for the dry-run)."""
+    """Full parameter tree (works under jax.eval_shape for the dry-run).
+
+    Jitted with ``cfg`` static, so the tree is built on the device and
+    each leaf is written straight in its dtype: the compiler fuses every
+    float32 draw into its cast, and no float32 copy of a stacked weight
+    ever exists (eagerly, yi-6b's (32, 4096, 11008) MLP stack alone would
+    take 5.8 GB in float32 on a 16 GB chip)."""
     if cfg.is_encoder_decoder:
         return _init_params_encdec(cfg, key)
     ks = jax.random.split(key, 5)
@@ -405,7 +423,7 @@ def _layer_prefill(cfg, p: Params, kind: str, x, *, positions, ctx,
         window = cfg.local_window if kind == "local" else None
         o, cache = attention_prefill(cfg, p["attn"], h, positions=positions,
                                      causal=True, window=window,
-                                     pad_to=max_seq)
+                                     pad_to=max_seq, plan=plan)
         x = x + o
     elif kind == "cross":
         h = norm(p["ln1"], x, cfg.norm)

@@ -504,12 +504,8 @@ def analyze(text: str) -> dict[str, Any]:
 
 
 def xla_cost_analysis(compiled) -> dict[str, Any]:
-    """XLA's own ``compiled.cost_analysis()``, normalized across jax
-    versions: the pinned jax 0.4.37 returns a one-element *list* of
-    per-program dicts, newer jax returns the dict directly, and some
-    backends return None.  Always a (possibly empty) dict — the
-    comparison baseline for this module's trip-aware numbers."""
+    """XLA's own ``compiled.cost_analysis()`` as a (possibly empty) dict —
+    some backends return None.  The comparison baseline for this module's
+    trip-aware numbers."""
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     return dict(ca) if ca else {}

@@ -78,12 +78,10 @@ def test_compressed_psum_single_shard_identity():
     """With axis size 1, compressed_psum == plain quantize roundtrip."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.distributed import shard_map
-
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     x = jax.random.normal(jax.random.PRNGKey(0), (128,))
 
-    f = shard_map(lambda v: C.compressed_psum(v, "data"), mesh=mesh,
+    f = jax.shard_map(lambda v: C.compressed_psum(v, "data"), mesh=mesh,
                   in_specs=P(), out_specs=P())
     out = f(x)
     q, s = C.quantize(x)

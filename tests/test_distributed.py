@@ -93,12 +93,12 @@ def test_compressed_psum_multi_device():
     check(run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.distributed import compression as C, shard_map
+from repro.distributed import compression as C
 
 mesh = Mesh(np.array(jax.devices()).reshape(8), ('data',))
 xs = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
-out = shard_map(lambda v: C.compressed_psum(v[0], 'data'), mesh=mesh,
+out = jax.shard_map(lambda v: C.compressed_psum(v[0], 'data'), mesh=mesh,
                 in_specs=P('data'), out_specs=P())(xs)
 exact = xs.mean(0)
 err = float(jnp.abs(out - exact).max())

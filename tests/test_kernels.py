@@ -1,17 +1,28 @@
 """Pallas kernel validation: interpret-mode vs the pure-jnp oracles,
 swept over shapes, dtypes and block sizes."""
+import functools
+import json
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.fused_mlp import fused_mlp
-from repro.kernels.gemm import gemm
-from repro.kernels.gemm_gelu import gemm_act
-from repro.kernels.mlstm import mlstm_scan
-from repro.kernels.rg_lru import rg_lru_scan
+from repro.core import hw
+from repro.kernels import flash_attention as _fa, fused_mlp as _fm, gemm as _g
+from repro.kernels import gemm_gelu as _ga, mlstm as _ml, ref, rg_lru as _rg
+from util import check, run_with_devices
+
+# the kernels take the scoped-VMEM limit of the target they were planned
+# for; these tests plan for the repo's serving target
+_LIMIT = dict(vmem_limit_bytes=hw.TPU_V5E.vmem_limit_bytes)
+flash_attention = functools.partial(_fa.flash_attention, **_LIMIT)
+fused_mlp = functools.partial(_fm.fused_mlp, **_LIMIT)
+gemm = functools.partial(_g.gemm, **_LIMIT)
+gemm_act = functools.partial(_ga.gemm_act, **_LIMIT)
+mlstm_scan = functools.partial(_ml.mlstm_scan, **_LIMIT)
+rg_lru_scan = functools.partial(_rg.rg_lru_scan, **_LIMIT)
 
 
 def rnd(key, shape, dtype, scale=1.0):
@@ -209,3 +220,114 @@ def test_ops_plan_blocks_are_legal():
     bq, bk = ops.plan_attention_blocks(4096, 4096, 128, "bfloat16",
                                        target=hw.TPU_V5E)
     assert 4096 % bq == 0 and 4096 % bk == 0
+
+
+
+def _ops_case(op, batch=1):
+    """(op's Pallas path, its jnp reference path, arguments); ``batch``
+    sizes the leading dim of the attention and recurrent operands."""
+    from repro.kernels import ops
+    f32 = jnp.float32
+    x, w = rnd(0, (128, 128), f32, 0.1), rnd(1, (128, 256), f32, 0.1)
+    w2, wg = rnd(2, (256, 128), f32, 0.1), rnd(3, (128, 256), f32, 0.1)
+    q = rnd(4, (batch, 4, 128, 64), f32)
+    k, v = rnd(5, (batch, 2, 128, 64), f32), rnd(6, (batch, 2, 128, 64), f32)
+    u = rnd(7, (batch, 64, 256), f32)
+    a = jax.nn.sigmoid(rnd(8, (batch, 64, 256), f32))
+    mq = [rnd(9 + i, (batch, 2, 64, 32), f32, 0.5) for i in range(3)]
+    gates = [rnd(12, (batch, 2, 64), f32),
+             rnd(13, (batch, 2, 64), f32) + 2.0]
+    fn, args, kw = {
+        "gemm": (ops.gemm, (x, w), {}),
+        "gemm_act": (ops.gemm_act, (x, w), dict(act="silu")),
+        "fused_mlp": (ops.fused_mlp, (x, w, w2, wg), dict(act="silu")),
+        "attention": (ops.attention, (q, k, v), dict(causal=True)),
+        "rg_lru": (lambda *xs, backend: ops.rg_lru(*xs, backend=backend)[0],
+                   (u, a), {}),
+        "mlstm": (ops.mlstm, (*mq, *gates), {}),
+    }[op]
+    return (functools.partial(fn, backend="pallas", **kw),
+            functools.partial(fn, backend="ref", **kw), args)
+
+
+@pytest.mark.parametrize("op", ["gemm", "gemm_act", "fused_mlp",
+                                "attention", "rg_lru", "mlstm"])
+def test_pallas_ops_differentiate_like_reference(op):
+    """A pallas_call has no derivative of its own: the kernel path of each
+    op must give the gradient of its jnp reference (a TPU train step runs
+    the kernels forward)."""
+    pallas, reference, args = _ops_case(op)
+    argnums = tuple(range(len(args)))
+
+    def grads(fn):
+        return jax.grad(lambda *xs: jnp.sum(jnp.sin(fn(*xs))), argnums)(
+            *args)
+
+    for g, r in zip(grads(pallas), grads(reference)):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5)
+
+
+_OPS = ["gemm", "gemm_act", "fused_mlp", "attention", "rg_lru", "mlstm"]
+
+
+@pytest.fixture(scope="module")
+def per_shard():
+    """Each op's Pallas path (interpret mode) inside a program partitioned
+    over a 2x2 ("data", "model") mesh of four host devices, against the
+    same path on one device: max abs difference of its value and
+    gradients, and whether the kernel ran under shard_map."""
+    proc = run_with_devices(f"""
+import json, sys
+sys.path.insert(0, {str(pathlib.Path(__file__).parent)!r})
+import jax, jax.numpy as jnp, numpy as np
+from test_kernels import _ops_case
+from repro.distributed import act_sharding
+from repro.distributed.sharding import make_activation_policy
+from repro.launch.mesh import make_mesh
+
+def program(pallas, n):
+    # a fresh function per trace: jit must not reuse the other's trace
+    def both(*xs):
+        loss = lambda *ys: jnp.sum(jnp.sin(pallas(*ys)))
+        return pallas(*xs), jax.grad(loss, tuple(range(n)))(*xs)
+    return both
+
+mesh = make_mesh((2, 2), ("data", "model"))
+out = {{}}
+for op in {_OPS!r}:
+    pallas, _, args = _ops_case(op, batch=2)
+    want = jax.jit(program(pallas, len(args)))(*args)
+    with act_sharding.use_policy(make_activation_policy(mesh, None)):
+        sharded = program(pallas, len(args))
+        got = jax.jit(sharded)(*args)
+        text = str(jax.make_jaxpr(sharded)(*args))
+    out[op] = dict(
+        err=max(float(np.max(np.abs(np.asarray(g) - np.asarray(w))))
+                for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want))),
+        per_shard="shard_map" in text)
+print(json.dumps(out))
+""", n_devices=4)
+    check(proc)
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("op", _OPS)
+def test_pallas_ops_run_per_shard_on_a_mesh(per_shard, op):
+    """XLA cannot partition a Mosaic kernel: in a program partitioned over
+    a mesh each Pallas op runs once per shard, and its value and
+    gradients match the unpartitioned kernel."""
+    assert per_shard[op]["per_shard"], per_shard[op]
+    assert per_shard[op]["err"] < 1e-4, per_shard[op]
+
+
+def test_pallas_ops_refuse_a_policy_without_mesh(monkeypatch):
+    """'auto' stays on the kernels inside a partitioned program; a policy
+    that names no mesh cannot place them and raises, it never rebinds."""
+    from repro.distributed import act_sharding
+    from repro.kernels import ops
+    pallas, _, args = _ops_case("gemm")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with act_sharding.use_policy(lambda x, kind: x):
+        assert ops._resolve("auto") == "pallas"
+        with pytest.raises(ValueError, match="no mesh"):
+            pallas(*args)

@@ -12,6 +12,9 @@ from util import SRC
 def run_py(args, timeout=540):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    # the launchers' mains place JAX's persistent compile cache in the
+    # checkout; a test run must neither fill it nor read programs back
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     return subprocess.run([sys.executable] + args, env=env,
                           capture_output=True, text=True, timeout=timeout)
 

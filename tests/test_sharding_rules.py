@@ -11,8 +11,8 @@ from repro.distributed.sharding import (
     _div, axis_size, batch_pspecs, cache_pspecs, dp_axes, param_pspecs)
 from repro.models import model as M
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_div_guards_divisibility():

@@ -115,9 +115,12 @@ class TestDetectTarget:
             assert len(t.levels) == 3
 
     def test_unknown_platform_falls_back_to_v5e(self):
-        assert hw.detect_target([_FakeDev("gpu", "NVIDIA H100")]) \
-            is hw.TPU_V5E
-        assert hw.detect_target([]) is hw.TPU_V5E
+        """No fallback any more: a platform without a described hierarchy
+        (and an empty device list) raises instead of planning for v5e."""
+        with pytest.raises(ValueError, match="platform 'gpu'"):
+            hw.detect_target([_FakeDev("gpu", "NVIDIA H100")])
+        with pytest.raises(ValueError):
+            hw.detect_target([])
 
     def test_default_target_uses_detection(self, monkeypatch):
         """default_target resolution: set_default_target override, then

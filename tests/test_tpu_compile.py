@@ -1,0 +1,154 @@
+"""The Pallas kernels compile for a TPU v5e at real model widths.
+
+Interpret mode (``tests/test_kernels.py``) checks the numerics but not
+what the chip's compiler accepts: scoped-VMEM limits, block alignment,
+vector layouts.  These tests lower every kernel at the block sizes the
+FTL planner picks for yi-6b (and the recurrent kernels at the widths of
+recurrentgemma-9b and xlstm-1.3b) and compile it against a described
+``v5e:2x2`` topology — the chip's compiler, with no chip attached.
+
+The planned kernels compile under a limit of the planner's own footprint
+estimate plus ``hw.PALLAS_VMEM_HEADROOM``, tighter than the target's
+``vmem_limit_bytes`` they ask for in use: Mosaic's internal scratch comes
+on top of the tiles the planner counts (about 8.5 MiB for the fused MLP
+at m=4096), and the headroom is what must cover it.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU compiler library, and each test
+worker imports every test file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.core import ftl, hw
+from repro.kernels import ops
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.fused_mlp import fused_mlp
+from repro.kernels.gemm import gemm
+from repro.kernels.gemm_gelu import gemm_act
+from repro.kernels.mlstm import mlstm_scan
+from repro.kernels.rg_lru import rg_lru_scan
+
+TARGET = hw.TPU_V5E
+YI = get_config("yi-6b")
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler library on this host
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _limit(plan) -> int:
+    """Scoped-VMEM limit for a planned kernel: the planner's footprint
+    estimate plus the Pallas headroom (at most ``vmem_limit_bytes``)."""
+    return plan.vmem_bytes + hw.PALLAS_VMEM_HEADROOM
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("m", [512, 4096])
+def test_fused_mlp_compiles_at_yi6b_width(one_chip, m):
+    d, f = YI.d_model, YI.d_ff
+    bm, bf = ops.plan_mlp_blocks(m, d, f, "bfloat16", True, YI.mlp_act,
+                                 TARGET)
+    plan = ftl.solve(ftl.fusion.mlp(m=m, d_model=d, d_ff=f, dtype="bfloat16",
+                                    gated=True, act=YI.mlp_act, fuse=True),
+                     target=TARGET, whole_dims=frozenset({"K", "N"}))
+    assert (plan.tile("M"), plan.tile("F")) == (bm, bf)
+    _compile(lambda x, w1, w2, wg: fused_mlp(
+        x, w1, w2, wg, act=YI.mlp_act, block_m=bm, block_f=bf,
+        vmem_limit_bytes=_limit(plan)),
+        one_chip, ((m, d), BF16), ((d, f), BF16), ((f, d), BF16),
+        ((d, f), BF16))
+
+
+@pytest.mark.parametrize("t", [2048, 4096])
+def test_flash_attention_compiles_at_yi6b_width(one_chip, t):
+    hq, hk, dh = YI.n_heads, YI.n_kv_heads, YI.resolved_head_dim
+    bq, bk = ops.plan_attention_blocks(t, t, dh, "bfloat16", TARGET)
+    plan = ftl.plan_fixed(ftl.attention_graph(q_len=t, kv_len=t, head_dim=dh,
+                                              dtype="bfloat16"),
+                          (), target=TARGET).segments[0].plan
+    _compile(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=bq, block_k=bk,
+        vmem_limit_bytes=_limit(plan)),
+        one_chip, ((1, hq, t, dh), BF16), ((1, hk, t, dh), BF16),
+        ((1, hk, t, dh), BF16))
+
+
+def test_gemm_compiles_at_yi6b_attention_projection(one_chip):
+    m, d, n = 2048, YI.d_model, YI.d_model
+    bm, bn, bk = ops.plan_gemm_blocks(m, d, n, "bfloat16", None, TARGET)
+    plan = ftl.solve(ftl.fusion.gemm_chain(m=m, dims_kn=[d, n],
+                                           dtype="bfloat16"), target=TARGET)
+    _compile(lambda x, w: gemm(
+        x, w, block_m=bm, block_n=bn, block_k=bk,
+        vmem_limit_bytes=_limit(plan)),
+        one_chip, ((m, d), BF16), ((d, n), BF16))
+
+
+def test_gemm_act_compiles_at_yi6b_up_projection(one_chip):
+    m, d, f = 2048, YI.d_model, YI.d_ff
+    bm, bn, bk = ops.plan_gemm_blocks(m, d, f, "bfloat16", YI.mlp_act,
+                                      TARGET)
+    plan = ftl.solve(ftl.fusion.gemm_act(m=m, k=d, n=f, dtype="bfloat16",
+                                         act=YI.mlp_act), target=TARGET)
+    _compile(lambda x, w: gemm_act(
+        x, w, act=YI.mlp_act, block_m=bm, block_n=bn, block_k=bk,
+        vmem_limit_bytes=_limit(plan)),
+        one_chip, ((m, d), BF16), ((d, f), BF16))
+
+
+def test_rg_lru_compiles_at_recurrentgemma9b_width(one_chip):
+    w = get_config("recurrentgemma-9b").lru_width
+    shape = ((1, 2048, w), BF16)
+    _compile(lambda x, a: rg_lru_scan(
+        x, a, vmem_limit_bytes=TARGET.vmem_limit_bytes),
+        one_chip, shape, shape)
+
+
+def test_mlstm_compiles_at_xlstm13b_width(one_chip):
+    cfg = get_config("xlstm-1.3b")
+    h = cfg.n_heads
+    dh = cfg.xlstm_expand * cfg.d_model // h
+    qkv = ((1, h, 2048, dh), BF16)
+    gate = ((1, h, 2048), jnp.float32)
+    _compile(lambda q, k, v, i, f: mlstm_scan(
+        q, k, v, i, f, vmem_limit_bytes=TARGET.vmem_limit_bytes),
+        one_chip, qkv, qkv, qkv, gate, gate)
