@@ -27,6 +27,7 @@ import dataclasses
 import functools
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 
 from repro import obs
@@ -281,17 +282,17 @@ def run_block(
     dtype = str(x.dtype)
     mode = ftl_mode if ftl_mode is not None else cfg.ftl_mode
 
-    # Per-stage spans carry the *resolved* executor in the name.  Under
-    # jax.jit these time the trace/lowering of the stage, not device
-    # execution (XLA fuses across stage boundaries); on the eager path
-    # (and on every re-trace) they are the stage's wall-clock.
+    # Each stage runs under a named scope carrying the *resolved*
+    # executor (``seg:attn:<executor>`` / ``seg:mlp:<executor>``): the
+    # compiled operations keep it in their metadata, so a device trace
+    # names the planned segment each operation belongs to.
     if "attn" in params:
         nh, hk, dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
         if positions is None:
             positions = jnp.arange(s)
         gemm_ex = _resolve_gemm(plan, mode, s, dtype)
         attn_ex = _resolve_attention(plan, mode, s, dtype)
-        with obs.span(f"seg:attn:{attn_ex.name}", "exec"):
+        with jax.named_scope(f"seg:attn:{attn_ex.name}"):
             ap = params["attn"]
             h = L.norm(params["ln1"], x, cfg.norm)
             q = L._split_heads(project(gemm_ex, h, ap["wq"]), nh)
@@ -319,7 +320,7 @@ def run_block(
             d_ff=w1.shape[1],
             gated=mp.get("wg", {}).get("w") is not None,
         )
-        with obs.span(f"seg:mlp:{mlp_ex.name}", "exec"):
+        with jax.named_scope(f"seg:mlp:{mlp_ex.name}"):
             wg = mp.get("wg", {}).get("w")
             h = L.norm(params["ln2"], x, cfg.norm)
             y = mlp_ex.run(

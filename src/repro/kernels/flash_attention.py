@@ -135,6 +135,7 @@ def flash_attention(
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
+        name="flash_attention",
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, hq, tq, dh)

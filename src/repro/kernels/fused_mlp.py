@@ -127,5 +127,6 @@ def fused_mlp(
         scratch_shapes=[pltpu.VMEM((block_m, n), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
+        name="fused_mlp",
         interpret=interpret,
     )(*args)

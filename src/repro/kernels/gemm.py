@@ -65,5 +65,6 @@ def gemm(
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
+        name="gemm",
         interpret=interpret,
     )(x, w)

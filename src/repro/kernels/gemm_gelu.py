@@ -88,5 +88,6 @@ def gemm_act(
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
+        name="gemm_gelu",
         interpret=interpret,
     )(*args)

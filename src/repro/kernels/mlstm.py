@@ -143,6 +143,7 @@ def mlstm_scan(
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
+        name="mlstm",
         interpret=interpret,
     )(qf, kf, vf, if_, ff)
     return out.reshape(b, h, t, dh)

@@ -105,6 +105,7 @@ def rg_lru_scan(
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
+        name="rg_lru",
         interpret=interpret,
     )(x, a, h0.astype(jnp.float32).reshape(b, 1, d))
     return h, hT.reshape(b, d)

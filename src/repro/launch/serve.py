@@ -465,57 +465,67 @@ class ServeEngine:
             raise ValueError(f"request {req.rid}: prompt of {plen} tokens "
                              f"exceeds the largest bucket "
                              f"{self.buckets[-1]}")
+        t_admit = time.perf_counter()
         obslib.begin("serve:admit", "serve")
-        bucket, plan = self.plans.get(plen, "prefill")
-        req.bucket = bucket
-        if self.paged and not self.kv.allocate(slot, bucket):
-            obslib.end()
-            return False
+        try:
+            bucket, plan = self.plans.get(plen, "prefill")
+            req.bucket = bucket
+            if self.paged and not self.kv.allocate(slot, bucket):
+                return False
 
-        padded = np.zeros(bucket, np.int32)
-        padded[:plen] = req.prompt
-        batch = {"tokens": jnp.asarray(padded)[None], **extras}
-        fn = self.prefill_fn(bucket, plan)
-        # bucket padding is on the right; the prompt's real last token
-        # sits at plen-1 and decode overwrites the pad KV in place
-        with obslib.span(f"serve:prefill:m{bucket}", "serve"):
-            logits, cache1 = fn(self.params, batch, jnp.int32(plen - 1))
-            logits.block_until_ready()
+            padded = np.zeros(bucket, np.int32)
+            padded[:plen] = req.prompt
+            batch = {"tokens": jnp.asarray(padded)[None], **extras}
+            fn = self.prefill_fn(bucket, plan)
+            # bucket padding is on the right; the prompt's real last token
+            # sits at plen-1 and decode overwrites the pad KV in place
+            with obslib.span(f"serve:prefill:m{bucket}", "serve"):
+                logits, cache1 = fn(self.params, batch, jnp.int32(plen - 1))
+                logits.block_until_ready()
 
-        if self.paged:
-            self.kv.write_prefill(slot, cache1, bucket)
-        else:
-            def splice(path, full, one):
-                """Insert request-batch-1 state into this slot of the
-                batch cache, padding the request's seq dims up to the
-                engine max.
+            if self.paged:
+                # dispatch only: the page write runs on the device after
+                with obslib.span("serve:admit:pages", "serve"):
+                    self.kv.write_prefill(slot, cache1, bucket)
+            else:
+                def splice(path, full, one):
+                    """Insert request-batch-1 state into this slot of the
+                    batch cache, padding the request's seq dims up to the
+                    engine max.
 
-                The batch axis is structural, not inferred from extents
-                (slot-count 1 made every axis look like batch): stacked
-                'layers' caches carry a leading layer dim → batch is
-                axis 1; remainder/unstacked caches → axis 0."""
-                names = [str(k.key) for k in path
-                         if isinstance(k, jax.tree_util.DictKey)]
-                ax = 1 if names and names[0] == "layers" else 0
-                if one.shape[ax + 1:] != full.shape[ax + 1:]:
-                    pads = [(0, 0)] * one.ndim
-                    for d in range(ax + 1, one.ndim):
-                        pads[d] = (0, full.shape[d] - one.shape[d])
-                    one = jnp.pad(one, pads)
-                return _dus_axis(full, jnp.take(one, 0, axis=ax), slot, ax)
+                    The batch axis is structural, not inferred from
+                    extents (slot-count 1 made every axis look like
+                    batch): stacked 'layers' caches carry a leading layer
+                    dim → batch is axis 1; remainder/unstacked caches →
+                    axis 0."""
+                    names = [str(k.key) for k in path
+                             if isinstance(k, jax.tree_util.DictKey)]
+                    ax = 1 if names and names[0] == "layers" else 0
+                    if one.shape[ax + 1:] != full.shape[ax + 1:]:
+                        pads = [(0, 0)] * one.ndim
+                        for d in range(ax + 1, one.ndim):
+                            pads[d] = (0, full.shape[d] - one.shape[d])
+                        one = jnp.pad(one, pads)
+                    return _dus_axis(full, jnp.take(one, 0, axis=ax), slot,
+                                     ax)
 
-            self.cache = jax.tree_util.tree_map_with_path(
-                splice, self.cache, cache1)
+                self.cache = jax.tree_util.tree_map_with_path(
+                    splice, self.cache, cache1)
 
-        self.active[slot] = req
-        self.pos[slot] = plen
-        req.out.append(int(jnp.argmax(logits[0, -1])))
-        req.t_admitted = time.perf_counter()
-        self.stats["prefills"] += 1
-        adm = self.stats["bucket_admissions"]
-        adm[bucket] = adm.get(bucket, 0) + 1
-        obslib.end()  # serve:admit
-        return True
+            self.active[slot] = req
+            self.pos[slot] = plen
+            with obslib.span("serve:admit:first_token", "serve"):
+                first = int(jnp.argmax(logits[0, -1]))
+            req.out.append(first)
+            req.t_admitted = time.perf_counter()
+            self.stats["prefills"] += 1
+            adm = self.stats["bucket_admissions"]
+            adm[bucket] = adm.get(bucket, 0) + 1
+            # the request's wait: from due to this admission's start
+            obslib.record("serve:queue", "serve", req.t_arrival, t_admit)
+            return True
+        finally:
+            obslib.end()  # serve:admit
 
     # ------------------------------------------------------------------
     def _evict(self, slot: int) -> None:
@@ -529,68 +539,82 @@ class ServeEngine:
 
     def step(self):
         """One batched decode step for all active slots (each at its own
-        position)."""
+        position).
+
+        With recording on, four spans tile ``serve:decode_step``:
+        ``prepare`` (plan lookup, slot scan, page allocation, host
+        arrays), ``launch`` (the jitted step's dispatch), ``sync`` (the
+        sampling dispatch and the tokens' readback, which waits for the
+        device) and ``commit`` (token bookkeeping)."""
         t_step = time.perf_counter() if self.obs else 0.0
         obslib.begin("serve:decode_step", "serve")
-        # steady-state plan lookup: after warmup this always hits; a miss
-        # (or a changed plan object) would force a re-jit — counted as a
-        # replan, and gated to zero in bench_serve
-        _, plan = self.plans.get(1, "decode")
-        if plan is not self._decode_fn_plan:
-            self.decode_fn = self._build_decode(plan)
-            self._decode_fn_plan = plan
-            self.decode_plan = plan
-            self.stats["replans"] += 1
+        obslib.begin("serve:step:prepare", "serve")
+        try:
+            # steady-state plan lookup: after warmup this always hits; a
+            # miss (or a changed plan object) would force a re-jit —
+            # counted as a replan, and gated to zero in bench_serve
+            _, plan = self.plans.get(1, "decode")
+            if plan is not self._decode_fn_plan:
+                self.decode_fn = self._build_decode(plan)
+                self._decode_fn_plan = plan
+                self.decode_plan = plan
+                self.stats["replans"] += 1
 
-        tok = np.zeros((self.slots, 1), np.int32)
-        live = np.zeros(self.slots, bool)
-        for i, r in enumerate(self.active):
-            if r is not None and not r.done:
-                tok[i, 0] = r.out[-1]
-                live[i] = True
+            tok = np.zeros((self.slots, 1), np.int32)
+            live = np.zeros(self.slots, bool)
+            for i, r in enumerate(self.active):
+                if r is not None and not r.done:
+                    tok[i, 0] = r.out[-1]
+                    live[i] = True
 
-        if self.paged:
-            wblk = np.zeros(self.slots, np.int32)
-            woff = np.zeros(self.slots, np.int32)
-            for i in range(self.slots):
-                if live[i]:
-                    if not self.kv.allocate(i, int(self.pos[i]) + 1):
-                        raise RuntimeError(
-                            f"KV pool exhausted growing slot {i} at pos "
-                            f"{int(self.pos[i])} "
-                            f"({self.kv.free_blocks} free blocks)")
-                    wblk[i], woff[i] = self.kv.write_coords(
-                        i, int(self.pos[i]))
-                # dead slots keep (0, 0): the scratch page
-            logits, self.kv.pool = self.decode_fn(
-                self.params, self.kv.pool, self.kv.table_array(),
-                jnp.asarray(tok), jnp.asarray(self.pos),
-                jnp.asarray(wblk), jnp.asarray(woff))
-        else:
-            if self._vector_pos:
-                pos = jnp.asarray(self.pos)
+            if self.paged:
+                wblk = np.zeros(self.slots, np.int32)
+                woff = np.zeros(self.slots, np.int32)
+                for i in range(self.slots):
+                    if live[i]:
+                        if not self.kv.allocate(i, int(self.pos[i]) + 1):
+                            raise RuntimeError(
+                                f"KV pool exhausted growing slot {i} at "
+                                f"pos {int(self.pos[i])} "
+                                f"({self.kv.free_blocks} free blocks)")
+                        wblk[i], woff[i] = self.kv.write_coords(
+                            i, int(self.pos[i]))
+                    # dead slots keep (0, 0): the scratch page
+                args = (self.params, self.kv.pool, self.kv.table_array(),
+                        jnp.asarray(tok), jnp.asarray(self.pos),
+                        jnp.asarray(wblk), jnp.asarray(woff))
+                obslib.switch("serve:step:launch", "serve")
+                logits, self.kv.pool = self.decode_fn(*args)
             else:
-                pos = jnp.int32(int(max(
-                    (self.pos[i] for i, r in enumerate(self.active)
-                     if r is not None), default=0)))
-            logits, self.cache = self.decode_fn(
-                self.params, self.cache, jnp.asarray(tok), pos)
+                if self._vector_pos:
+                    pos = jnp.asarray(self.pos)
+                else:
+                    pos = jnp.int32(int(max(
+                        (self.pos[i] for i, r in enumerate(self.active)
+                         if r is not None), default=0)))
+                args = (self.params, self.cache, jnp.asarray(tok), pos)
+                obslib.switch("serve:step:launch", "serve")
+                logits, self.cache = self.decode_fn(*args)
 
-        nxt = np.asarray(jnp.argmax(logits[:, 0], -1))
-        now = time.perf_counter()
-        for i, r in enumerate(self.active):
-            if r is None or r.done:
-                continue
-            t = int(nxt[i])
-            r.out.append(t)
-            self.pos[i] += 1
-            self.stats["tokens"] += 1
-            if t == self.eos or len(r.out) >= r.max_new \
-                    or self.pos[i] >= self.max_seq - 1:
-                r.done = True
-                r.t_done = now
-        self.stats["decode_steps"] += 1
-        obslib.end()  # serve:decode_step
+            obslib.switch("serve:step:sync", "serve")
+            nxt = np.asarray(jnp.argmax(logits[:, 0], -1))
+            obslib.switch("serve:step:commit", "serve")
+            now = time.perf_counter()
+            for i, r in enumerate(self.active):
+                if r is None or r.done:
+                    continue
+                t = int(nxt[i])
+                r.out.append(t)
+                self.pos[i] += 1
+                self.stats["tokens"] += 1
+                if t == self.eos or len(r.out) >= r.max_new \
+                        or self.pos[i] >= self.max_seq - 1:
+                    r.done = True
+                    r.t_done = now
+            self.stats["decode_steps"] += 1
+        finally:
+            obslib.end()  # the open serve:step:* phase
+            obslib.end()  # serve:decode_step
         if self.obs:
             self._observe_step(time.perf_counter() - t_step)
 
@@ -634,39 +658,49 @@ class ServeEngine:
         queue = list(requests)
         done: list[Request] = []
         while queue or any(r is not None for r in self.active):
-            now = time.perf_counter()
-            if self.obs:
-                self._g_queue.set(len(queue))
-            admitted_any = False
-            for i in range(self.slots):
-                r = self.active[i]
-                if r is not None and r.done:
-                    done.append(r)
-                    self._evict(i)
-                if (self.active[i] is None and queue
-                        and queue[0].t_arrival <= now):
-                    if self._admit(queue[0], i, extras):
-                        queue.pop(0)
-                        admitted_any = True
-                    else:
-                        break       # paged pool full: wait for evictions
-            have_live = any(r is not None and not r.done
-                            for r in self.active)
-            if not have_live:
-                if admitted_any:
-                    continue
-                if queue:
-                    wait = queue[0].t_arrival - time.perf_counter()
-                    if wait > 0:
-                        time.sleep(min(wait, 0.05))
+            # host work between decode steps: evictions, admissions and
+            # the wait for an arrival
+            obslib.begin("serve:schedule", "serve")
+            try:
+                now = time.perf_counter()
+                if self.obs:
+                    self._g_queue.set(len(queue))
+                admitted_any = False
+                for i in range(self.slots):
+                    r = self.active[i]
+                    if r is not None and r.done:
+                        done.append(r)
+                        self._evict(i)
+                    if (self.active[i] is None and queue
+                            and queue[0].t_arrival <= now):
+                        if self._admit(queue[0], i, extras):
+                            queue.pop(0)
+                            admitted_any = True
+                        else:
+                            break   # paged pool full: wait for evictions
+                have_live = any(r is not None and not r.done
+                                for r in self.active)
+                if not have_live:
+                    if admitted_any:
                         continue
-                    if all(r is None for r in self.active):
-                        # head request arrived but cannot be admitted and
-                        # nothing is running to free pages
-                        raise RuntimeError(
-                            "deadlock: KV pool too small to admit request "
-                            f"{queue[0].rid} with every slot empty")
-                continue
+                    if queue:
+                        # from the same clock read as the admission test:
+                        # a head due after `now` waits, never deadlocks
+                        wait = queue[0].t_arrival - now
+                        if wait > 0:
+                            with obslib.span("serve:wait_arrival", "serve"):
+                                time.sleep(min(wait, 0.05))
+                            continue
+                        if all(r is None for r in self.active):
+                            # head request arrived but cannot be admitted
+                            # and nothing is running to free pages
+                            raise RuntimeError(
+                                "deadlock: KV pool too small to admit "
+                                f"request {queue[0].rid} with every slot "
+                                "empty")
+                    continue
+            finally:
+                obslib.end()  # serve:schedule
             self.step()
         return done
 

@@ -4,7 +4,8 @@ The observability layer the ISSUE-10 tentpole asks for.  Four pieces:
 
 * :mod:`repro.obs.spans` — monotonic-clock span recorder on a
   preallocated ring buffer (nesting + thread id, no allocation on the
-  hot path).  Off by default; ``obs.enable()`` turns recording on.
+  hot path).  Off by default; ``obs.enable()`` turns recording on, and
+  mirrors each span into the JAX profiler's trace.
 * :mod:`repro.obs.metrics` — named counters / gauges / histograms on a
   process-global registry, always on, with pull-style collectors for
   surfaces that keep their own counters (the plan-cache ledger,
@@ -28,13 +29,13 @@ from .metrics import (REGISTRY, Counter, Gauge, Histogram,
                       MetricsRegistry, collect, counter, gauge,
                       histogram, register_collector)
 from .spans import (Span, SpanRecorder, begin, disable, enable, enabled,
-                    end, recorder, span)
+                    end, record, recorder, span, switch)
 
 __all__ = [
     "spans", "metrics", "export", "drift",
     # spans
     "Span", "SpanRecorder", "enable", "disable", "enabled", "recorder",
-    "begin", "end", "span",
+    "begin", "end", "switch", "record", "span",
     # metrics
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "counter", "gauge", "histogram", "register_collector", "collect",

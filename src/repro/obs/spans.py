@@ -4,8 +4,9 @@ A *span* is one timed region of the runtime (a ``plan_chain`` call, one
 decode step, one executed stage).  The recorder is built for hot paths:
 
 * ``begin``/``end`` write into preallocated parallel slot lists — no
-  per-span object is allocated while recording (the ``Span`` dataclass
-  only materializes at ``drain()``/``snapshot()`` time);
+  per-span object is allocated while recording, bar the profiler
+  annotation below (the ``Span`` dataclass only materializes at
+  ``drain()``/``snapshot()`` time);
 * nesting depth is tracked per thread on a preallocated stack, so spans
   render as a properly nested flame graph in Perfetto;
 * the buffer is a fixed-capacity ring: when full, the oldest spans are
@@ -14,6 +15,14 @@ decode step, one executed stage).  The recorder is built for hot paths:
 Recording is **off by default**.  ``enable()`` flips a module-level flag
 checked by every helper, so an un-instrumented process pays one dict
 lookup + one ``if`` per call site.
+
+While the default recorder is on, each bracketed span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, closed with it: any
+profiler capture then shows the spans on the host thread's track, on the
+same clock as the device's operations.  The annotation class is looked
+up when ``enable()`` builds the recorder, so importing this module pulls
+no jax, and with recording off no annotation is made.  The recorder's
+own stamps stay on ``perf_counter``.
 """
 from __future__ import annotations
 
@@ -31,6 +40,8 @@ __all__ = [
     "recorder",
     "begin",
     "end",
+    "switch",
+    "record",
     "span",
 ]
 
@@ -59,13 +70,18 @@ class _ThreadState(threading.local):
         self.names: list[str | None] = [None] * _MAX_DEPTH
         self.cats: list[str | None] = [None] * _MAX_DEPTH
         self.t0s: list[float] = [0.0] * _MAX_DEPTH
+        self.anns: list = [None] * _MAX_DEPTH  # open profiler annotations
         self.depth = 0
 
 
 class SpanRecorder:
-    """Fixed-capacity ring buffer of completed spans."""
+    """Fixed-capacity ring buffer of completed spans.
 
-    def __init__(self, capacity: int = 4096):
+    ``annotate`` (a class such as ``jax.profiler.TraceAnnotation``, built
+    from a span's name and used as a context manager) mirrors every
+    bracketed span into another tracer; None mirrors nothing."""
+
+    def __init__(self, capacity: int = 4096, annotate=None):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = capacity
@@ -79,20 +95,27 @@ class SpanRecorder:
         self.dropped = 0  # spans overwritten before being drained
         self._lock = threading.Lock()
         self._tls = _ThreadState()
+        self._annotate = annotate
 
     # -- hot path ----------------------------------------------------------
 
-    def begin(self, name: str, cat: str = "runtime") -> None:
+    def begin(self, name: str, cat: str = "runtime",
+              t0: float | None = None) -> None:
         tls = self._tls
         d = tls.depth
         if d < _MAX_DEPTH:
             tls.names[d] = name
             tls.cats[d] = cat
-            tls.t0s[d] = time.perf_counter()
+            if self._annotate is not None:
+                ann = self._annotate(name)
+                ann.__enter__()
+                tls.anns[d] = ann
+            tls.t0s[d] = time.perf_counter() if t0 is None else t0
         tls.depth = d + 1
 
-    def end(self) -> None:
-        t1 = time.perf_counter()
+    def end(self, t1: float | None = None) -> None:
+        if t1 is None:
+            t1 = time.perf_counter()
         tls = self._tls
         d = tls.depth - 1
         if d < 0:  # unmatched end() (e.g. toggled mid-span): ignore
@@ -100,15 +123,36 @@ class SpanRecorder:
         tls.depth = d
         if d >= _MAX_DEPTH:  # was too deep to record; just unwind
             return
+        ann = tls.anns[d]
+        if ann is not None:
+            tls.anns[d] = None
+            ann.__exit__(None, None, None)
+        self._commit(tls.names[d], tls.cats[d], tls.t0s[d], t1, d)
+
+    def switch(self, name: str, cat: str = "runtime") -> None:
+        """End the innermost open span and begin ``name`` at the same
+        instant: consecutive phases that tile their parent."""
+        t = time.perf_counter()
+        self.end(t)
+        self.begin(name, cat, t)
+
+    def record(self, name: str, cat: str, t0: float, t1: float) -> None:
+        """Commit a span over ``[t0, t1]`` that no call stack brackets
+        (a request's wait in a queue), at the current nesting depth.  It
+        is not mirrored to the profiler."""
+        self._commit(name, cat, t0, t1, self._tls.depth)
+
+    def _commit(self, name: str, cat: str, t0: float, t1: float,
+                depth: int) -> None:
         with self._lock:
             i = self._recorded % self.capacity
             if self._recorded >= self.capacity:
                 self.dropped += 1
-            self._names[i] = tls.names[d]
-            self._cats[i] = tls.cats[d]
-            self._t0s[i] = tls.t0s[d]
+            self._names[i] = name
+            self._cats[i] = cat
+            self._t0s[i] = t0
             self._t1s[i] = t1
-            self._depths[i] = d
+            self._depths[i] = depth
             self._tids[i] = threading.get_ident()
             self._recorded += 1
 
@@ -172,12 +216,23 @@ class _SpanCM:
 _DEFAULT: SpanRecorder | None = None
 
 
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where jax is absent."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
 def enable(capacity: int | None = None) -> SpanRecorder:
-    """Turn on span recording; idempotent unless ``capacity`` changes."""
+    """Turn on span recording, mirrored into the JAX profiler's trace;
+    idempotent unless ``capacity`` changes."""
     global _DEFAULT
     if _DEFAULT is None or (capacity is not None
                             and capacity != _DEFAULT.capacity):
-        _DEFAULT = SpanRecorder(capacity or 4096)
+        _DEFAULT = SpanRecorder(capacity or 4096,
+                                annotate=_profiler_annotation())
     return _DEFAULT
 
 
@@ -204,6 +259,18 @@ def end() -> None:
     rec = _DEFAULT
     if rec is not None:
         rec.end()
+
+
+def switch(name: str, cat: str = "runtime") -> None:
+    rec = _DEFAULT
+    if rec is not None:
+        rec.switch(name, cat)
+
+
+def record(name: str, cat: str, t0: float, t1: float) -> None:
+    rec = _DEFAULT
+    if rec is not None:
+        rec.record(name, cat, t0, t1)
 
 
 class _MaybeSpan:

@@ -367,3 +367,188 @@ def test_monitor_metrics_emit(tmp_path):
     assert hb.stale_peers() == []
     assert _val("train_heartbeat_stamps_total") == stamps0 + 1
     assert _val("train_heartbeat_oldest_age_seconds") >= 0
+
+
+# ---------------------------------------------------------------------------
+# phase spans inside the serving engine, mirrored into the JAX profiler
+# ---------------------------------------------------------------------------
+
+_STEP_PHASES = ("serve:step:prepare", "serve:step:launch",
+                "serve:step:sync", "serve:step:commit")
+
+
+def test_switch_tiles_and_record_commits_an_interval():
+    rec = SpanRecorder(capacity=16)
+    rec.begin("outer", "t")
+    rec.begin("a", "t")
+    rec.switch("b", "t")
+    rec.end()
+    rec.record("waited", "t", 1.0, 2.5)
+    rec.end()
+    rows = {s.name: s for s in rec.snapshot()}
+    assert rows["a"].t1 == rows["b"].t0
+    assert rows["a"].depth == rows["b"].depth == 1
+    assert (rows["waited"].t0, rows["waited"].t1) == (1.0, 2.5)
+    assert rows["outer"].t0 <= rows["a"].t0 and rows["b"].t1 <= \
+        rows["outer"].t1
+
+
+def _tiny_engine(setup, **kw):
+    from repro.launch.serve import ServeEngine
+
+    cfg, params = setup
+    return ServeEngine(cfg, params, batch_slots=2, max_seq=32, eos_id=-1,
+                       **kw)
+
+
+def _prompts(setup, n, seed=0):
+    import numpy as np
+
+    from repro.launch.serve import Request
+
+    cfg, _ = setup
+    rng = np.random.default_rng(seed)
+    return [Request(i, rng.integers(2, cfg.vocab_size, size=6)
+                    .astype(np.int32), 4) for i in range(n)]
+
+
+def _fresh_recorder():
+    obs.disable()
+    return obs.enable(capacity=4096)
+
+
+def test_step_phases_tile_each_decode_step(setup):
+    eng = _tiny_engine(setup)
+    rec = _fresh_recorder()
+    try:
+        eng.run(_prompts(setup, 3), {})
+        rows = rec.snapshot()
+    finally:
+        obs.enable()
+    steps = [s for s in rows if s.name == "serve:decode_step"]
+    assert steps
+    for step in steps:
+        inside = [s for s in rows if s.name.startswith("serve:step:")
+                  and step.t0 <= s.t0 and s.t1 <= step.t1]
+        assert tuple(s.name for s in inside) == _STEP_PHASES
+        for a, b in zip(inside, inside[1:]):
+            assert a.t1 == b.t0              # no gap between phases
+        assert sum(s.duration_s for s in inside) == pytest.approx(
+            step.duration_s, rel=0.05)
+    # the loop between steps is spanned, and no new name can be taken
+    # for a step or a prefill by the span counts that read those
+    names = {s.name for s in rows}
+    assert "serve:schedule" in names
+    assert all(n == "serve:decode_step" or n in _STEP_PHASES
+               or not n.startswith("serve:decode_step") for n in names)
+
+
+def test_one_queue_span_per_admission_from_its_arrival(setup):
+    eng = _tiny_engine(setup)
+    eng.run(_prompts(setup, 1), {})       # compile outside the timing
+    reqs = _prompts(setup, 4, seed=1)
+    rec = _fresh_recorder()
+    try:
+        # the last arrives after the first three have finished
+        done = eng.run(list(reqs), {}, arrivals=[0.0, 0.001, 0.002, 0.5])
+        rows = rec.snapshot()
+    finally:
+        obs.enable()
+    assert len(done) == 4
+    queue = [s for s in rows if s.name == "serve:queue"]
+    admits = [s for s in rows if s.name == "serve:admit"]
+    assert len(queue) == len(admits) == 4
+    assert sorted(s.t0 for s in queue) == sorted(r.t_arrival for r in reqs)
+    for q in queue:
+        # it ends where its admission begins (two clock reads apart)
+        assert min(a.t0 - q.t1 for a in admits if a.t0 >= q.t1) < 0.05
+    assert any(s.name == "serve:wait_arrival" for s in rows)
+    assert {"serve:admit:pages", "serve:admit:first_token"} <= {
+        s.name for s in rows}
+
+
+class _StopAfter(list):
+    """A request's output list that raises once it holds ``n`` tokens, as
+    a caller that ends serving from inside a token append does."""
+
+    def __init__(self, n):
+        super().__init__()
+        self.n = n
+
+    def append(self, token):
+        if len(self) >= self.n:
+            raise KeyboardInterrupt("stop")
+        super().append(token)
+
+
+def test_an_exception_from_a_token_append_leaves_the_stack_balanced(setup):
+    eng = _tiny_engine(setup)
+    req = _prompts(setup, 1)[0]
+    req.out = _StopAfter(2)
+    rec = _fresh_recorder()
+    try:
+        with pytest.raises(KeyboardInterrupt):
+            eng.run([req], {})
+        rec.begin("probe", "t")
+        rec.end()
+        rows = rec.snapshot()
+    finally:
+        obs.enable()
+    assert rows[-1].name == "probe" and rows[-1].depth == 0
+    # the step that raised closed its phase and itself
+    assert rows[-2].name == "serve:decode_step"
+    assert rows[-3].name == "serve:step:commit"
+
+
+class _CountingAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation."""
+    made: list = []
+
+    def __init__(self, name):
+        self.name, self.open = name, False
+        _CountingAnnotation.made.append(self)
+
+    def __enter__(self):
+        self.open = True
+        return self
+
+    def __exit__(self, *exc):
+        self.open = False
+
+
+def test_spans_mirror_into_profiler_annotations_only_while_on(
+        setup, monkeypatch):
+    import jax.profiler
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
+    _CountingAnnotation.made = []
+    eng = _tiny_engine(setup)
+    obs.disable()
+    try:
+        eng.run(_prompts(setup, 2), {})
+        assert obs.recorder() is None and _CountingAnnotation.made == []
+        rec = obs.enable(capacity=4096)
+        eng.run(_prompts(setup, 2), {})
+        rows = rec.snapshot()
+    finally:
+        obs.disable()
+        obs.enable()
+    made = _CountingAnnotation.made
+    assert made and not any(a.open for a in made)
+    # every bracketed span has its annotation; a queue wait has none
+    assert sorted(a.name for a in made) == sorted(
+        s.name for s in rows if s.name != "serve:queue")
+
+
+def test_importing_obs_pulls_no_jax():
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    src = pathlib.Path(__file__).resolve().parent.parent / "src"
+    env = dict(os.environ, PYTHONPATH=str(src))
+    code = ("import sys, repro.obs; "
+            "sys.exit('jax' in sys.modules)")
+    assert subprocess.run([sys.executable, "-c", code], env=env,
+                          timeout=120).returncode == 0

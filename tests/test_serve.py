@@ -182,6 +182,34 @@ def test_open_loop_arrivals_and_latency(setup):
         assert r.latency_s > 0
 
 
+def test_arrival_between_clock_reads_waits_and_admits(setup, monkeypatch):
+    """An idle engine whose queue head becomes due between the loop's
+    admission test and its wait (clock 100.009 -> 100.011 across an
+    arrival due at 100.01) waits for it and admits it, instead of
+    reporting a deadlock."""
+    from types import SimpleNamespace
+
+    from repro.launch import serve
+
+    cfg, params = setup
+    eng = ServeEngine(cfg, params, batch_slots=1, max_seq=32, eos_id=-1)
+    reads = iter([100.0, 100.009, 100.011])
+    last = [100.011]
+
+    def clock():
+        # the scripted reads, then 1 ms a read
+        last[0] = next(reads, last[0] + 1e-3)
+        return last[0]
+
+    monkeypatch.setattr(serve, "time", SimpleNamespace(
+        perf_counter=clock, sleep=lambda s: None))
+    req = Request(0, np.arange(2, 8, dtype=np.int32), 3)
+    done = eng.run([req], {}, arrivals=[0.01])
+    assert [r.rid for r in done] == [0] and len(req.out) == 3
+    assert req.t_arrival == pytest.approx(100.01)
+    assert req.t_admitted >= 100.011
+
+
 def test_decode_plan_differs_from_prefill_on_rv32_npu():
     """The m=1 decode shape runs through the same partition DP and, being
     memory-bound, picks different cuts than prefill on the NPU-equipped
