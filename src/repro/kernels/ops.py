@@ -29,6 +29,7 @@ from .fused_mlp import fused_mlp as _fused_mlp
 from .gemm import gemm as _gemm
 from .gemm_gelu import gemm_act as _gemm_act
 from .mlstm import mlstm_scan as _mlstm
+from . import paged_attention as _paged
 from .rg_lru import rg_lru_scan as _rg_lru
 
 Backend = Literal["auto", "pallas", "ref"]
@@ -326,6 +327,23 @@ def attention(q, k, v, *, causal: bool = True, window: int | None = None,
     # query heads do too, and each shard keeps whole GQA groups)
     spec = P(_dp(mesh, q.shape[0]), _tp(mesh, k.shape[1]), None, None)
     return _per_shard(body, mesh, (q, k, v), (spec,) * 3, spec)
+
+
+def paged_attention(q, k_pages, v_pages, blocks, lengths, *,
+                    backend: Backend = "auto",
+                    target: hwlib.Target | None = None):
+    """Decode attention over KV pages read in place: ``(acc, m, l)`` of
+    each row's cached positions (``ref.paged_attention``).  The Pallas
+    kernel where the pages fit its tiles (``paged_attention.supported``),
+    the jnp gather otherwise."""
+    bs, hk = k_pages.shape[1], k_pages.shape[2]
+    if _resolve(backend) == "ref" or not _paged.supported(bs, hk,
+                                                          k_pages.dtype):
+        return _ref.paged_attention(q, k_pages, v_pages, blocks, lengths)
+    return _paged.paged_attention(
+        q, k_pages, v_pages, blocks, lengths,
+        vmem_limit_bytes=_kernel_target(target).vmem_limit_bytes,
+        interpret=_interpret())
 
 
 def rg_lru(x, a, h0=None, *, backend: Backend = "auto"):

@@ -112,6 +112,42 @@ def attention(
     return o.reshape(b, hq, tq, dh).astype(q.dtype)
 
 
+def paged_attention(
+    q: jax.Array,          # (B, Hq, Dh)
+    k_pages: jax.Array,    # (P, bs, Hk, Dh)
+    v_pages: jax.Array,    # (P, bs, Hk, Dh)
+    blocks: jax.Array,     # (B, W) int32 page of each row's w-th block
+    lengths: jax.Array,    # (B,) int32 cached positions of each row
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Row ``b``'s attention state over its cached positions ``<
+    lengths[b]``, read from pages ``blocks[b]``: ``(acc (B, Hq, Dh), m (B,
+    Hq), l (B, Hq))``, all f32, with ``acc / l`` the attention output and
+    ``m`` the max score (-1e30 where a row has no position).
+
+    One ``take`` reads a row's pages as ``(W * bs * Hk, Dh)``: key ``c``
+    is position ``c // Hk`` of KV head ``c % Hk``.  Every query head
+    scores against every key, and a mask keeps its own head, so the
+    masked scores weigh exactly zero and the keys need no relayout."""
+    b, hq, dh = q.shape
+    hk = k_pages.shape[2]
+
+    def read(pages):
+        return jnp.take(pages, blocks, axis=0).reshape(b, -1, dh)
+
+    k, v = read(k_pages), read(v_pages)
+    key = jnp.arange(k.shape[1])
+    own = (key[None, None] % hk == (jnp.arange(hq) // (hq // hk))[None, :,
+                                                                  None]) \
+        & (key[None, None] // hk < lengths[:, None, None])   # (B, Hq, keys)
+    s = jnp.einsum("bqd,bcd->bqc", q, k,
+                   preferred_element_type=jnp.float32) * dh ** -0.5
+    m = jnp.where(own, s, -1e30).max(-1)
+    p = jnp.where(own, jnp.exp(s - m[..., None]), 0.0)
+    acc = jnp.einsum("bqc,bcd->bqd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return acc, m, p.sum(-1)
+
+
 def attention_blockwise(
     q: jax.Array,   # (B, Hq, Tq, Dh)
     k: jax.Array,   # (B, Hk, Tk, Dh)

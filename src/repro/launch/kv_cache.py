@@ -1,8 +1,7 @@
 """Paged (blocked) KV cache for the continuous-batching serving engine.
 
-The attention executor already tiles over the sequence, so the cache can
-be backed by fixed-size sequence blocks ("pages") allocated per slot
-instead of one dense ``(slots, max_seq, ...)`` tensor per layer:
+The cache is backed by fixed-size sequence blocks ("pages") allocated
+per slot instead of one dense ``(slots, max_seq, ...)`` tensor per layer:
 
 * The physical pool is ``models.model.init_cache(cfg, num_blocks + 1,
   block_size)`` — the *batch* axis of every cache leaf plays the physical
@@ -17,13 +16,16 @@ instead of one dense ``(slots, max_seq, ...)`` tensor per layer:
   to the free list on eviction — the continuous-batching scheduler's
   admission control can therefore run the pool smaller than
   ``slots * blocks_per_slot`` and queue requests under memory pressure.
-* :func:`gather_dense` / :func:`scatter_token` are the jit-traceable
-  halves of a decode step: gather materializes the per-slot dense view
-  ``(slots, max_seq, ...)`` from the pool (one ``take`` + reshape per
-  leaf), and scatter writes each slot's single new KV token back to its
-  ``(block, offset)`` coordinate.  The serving engine fuses
-  gather → model.decode_step → scatter into one jitted function, so the
-  dense view never round-trips to host memory.
+* A decode step (``launch.serve.make_paged_step``) reads the pool in
+  place: ``models.model.decode_step_paged`` hands each layer its pages
+  through the block table inside the layer scan, and
+  ``kernels.ops.paged_attention`` reads them where they lie (on the TPU
+  a Pallas kernel copies each slot's live pages into VMEM; elsewhere a
+  jnp ``take``).  The new token enters the softmax directly, and the
+  step returns only the new tokens' K/V, which :func:`write_tokens`
+  stores at their ``(block, offset)`` with one row scatter per leaf into
+  the donated pool.  No dense per-slot view is built and no leaf of the
+  pool is copied.
 
 Paging requires every decode-cache leaf to be a full-attention KV tensor
 with the model's uniform ``(batch, seq, Hk, Dh)`` layout —
@@ -32,6 +34,9 @@ with the model's uniform ``(batch, seq, Hk, Dh)`` layout —
 dense per-slot path in the engine).
 """
 from __future__ import annotations
+
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -49,76 +54,48 @@ def paged_supported(cfg) -> bool:
     return all(k == "attn" for k in [*kinds, *rem_kinds])
 
 
-def _block_axis(path) -> int:
-    """Physical-block axis of a pool leaf: stacked 'layers' leaves carry a
-    leading layer dim → axis 1; remainder leaves → axis 0 (the same
-    structural rule the engine's dense splice uses)."""
-    names = [str(k.key) for k in path
-             if isinstance(k, jax.tree_util.DictKey)]
-    return 1 if names and names[0] == "layers" else 0
+def _pages(leaf, blocks: jax.Array) -> jax.Array:
+    """Rows of physical blocks ``blocks`` in a pool leaf viewed as ``(... *
+    (NB+1), bs, Hk, Dh)`` (a free reshape: only leading dims merge):
+    ``l * (NB+1) + block`` for each layer ``l`` of a stacked leaf, which
+    adds a leading layer dim."""
+    lead = leaf.shape[:-4]
+    layer = jnp.arange(math.prod(lead)).reshape(lead + (1,) * blocks.ndim)
+    return layer * leaf.shape[-4] + blocks
 
 
-def gather_dense(pool, tables: jax.Array):
-    """Pool → per-slot dense cache view.
+def write_tokens(pool, new, wblk: jax.Array, woff: jax.Array):
+    """Store each slot's new KV token at its physical (block, offset).
 
-    ``tables``: (slots, blocks_per_slot) int32 physical block ids.  Each
-    leaf ``(..., NB+1, bs, ...)`` gathers its mapped pages and merges
-    them into ``(..., slots, blocks_per_slot * bs, ...)`` — exactly the
-    shape ``model.decode_step`` expects for ``max_seq =
-    blocks_per_slot * bs``.  Unmapped entries read the scratch page;
-    decode masks them out (position mask covers only ``<= pos``).
+    ``new`` mirrors the pool's tree (``models.model.decode_step_paged``):
+    stacked leaves ``(L, slots, Hk, Dh)``, remainder ``(slots, Hk, Dh)``;
+    ``wblk``/``woff`` ``(slots,)``, with inactive slots at the scratch page
+    (block 0).  Each leaf is viewed as token rows ``(... * bs, Hk, Dh)``
+    and written by one row scatter, which a donated pool takes in place.
     """
-    slots, w = tables.shape
+    def w(leaf, tok):
+        rows = _pages(leaf, wblk) * leaf.shape[-3] + woff
+        flat = leaf.reshape(-1, *leaf.shape[-2:]).at[rows.reshape(-1)].set(
+            tok.reshape(-1, *tok.shape[-2:]), mode="promise_in_bounds")
+        return flat.reshape(leaf.shape)
 
-    def g(path, leaf):
-        ax = _block_axis(path)
-        taken = jnp.take(leaf, tables.reshape(-1), axis=ax)
-        sh = taken.shape
-        bs = sh[ax + 1]
-        return taken.reshape(sh[:ax] + (slots, w * bs) + sh[ax + 2:])
-
-    return jax.tree_util.tree_map_with_path(g, pool)
+    return jax.tree.map(w, pool, new)
 
 
-def scatter_token(pool, dense, pos: jax.Array, wblk: jax.Array,
-                  woff: jax.Array):
-    """Write each slot's newly-decoded KV token back into the pool.
-
-    ``dense`` is the post-decode dense view; ``pos`` (slots,) is each
-    slot's write position inside its dense view, ``wblk``/``woff``
-    (slots,) its physical (block, offset) coordinate — inactive slots
-    point at the scratch page (block 0).
-    """
-    def s(path, pleaf, dleaf):
-        ax = _block_axis(path)
-        seq_ax = ax + 1
-        idx_shape = [1] * dleaf.ndim
-        idx_shape[ax] = pos.shape[0]
-        idx = pos.reshape(idx_shape)
-        tok = jnp.take_along_axis(dleaf, idx, axis=seq_ax)
-        tok = jnp.squeeze(tok, axis=seq_ax)        # (..., slots, Hk, Dh)
-        if ax == 1:
-            return pleaf.at[:, wblk, woff].set(tok)
-        return pleaf.at[wblk, woff].set(tok)
-
-    return jax.tree_util.tree_map_with_path(s, pool, dense)
-
-
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0)
 def _write_pages(pool, cache1, blocks: jax.Array):
     """Write one request's prefill cache (batch-1, seq = n_pages * bs)
-    into its allocated pages (jitted; retraces per page count)."""
-    def s(path, pleaf, cleaf):
-        ax = _block_axis(path)
-        bs = pleaf.shape[ax + 1]
-        c = jnp.squeeze(cleaf, axis=ax)            # drop request batch-1
-        sh = c.shape
-        c = c.reshape(sh[:ax] + (blocks.shape[0], bs) + sh[ax + 1:])
-        if ax == 1:
-            return pleaf.at[:, blocks].set(c)
-        return pleaf.at[blocks].set(c)
+    into its allocated pages of the donated pool, one token row at a
+    time as :func:`write_tokens` does (jitted; retraces per page
+    count)."""
+    def s(leaf, c):
+        bs = leaf.shape[-3]
+        rows = _pages(leaf, blocks[:, None]) * bs + jnp.arange(bs)
+        flat = leaf.reshape(-1, *leaf.shape[-2:]).at[rows.reshape(-1)].set(
+            c.reshape(-1, *c.shape[-2:]), mode="promise_in_bounds")
+        return flat.reshape(leaf.shape)
 
-    return jax.tree_util.tree_map_with_path(s, pool, cache1)
+    return jax.tree.map(s, pool, cache1)
 
 
 class PagedKVCache:

@@ -148,6 +148,19 @@ def _default_buckets(max_seq: int, block_size: int) -> tuple[int, ...]:
     return tuple(rungs)
 
 
+def make_paged_step(cfg, plan):
+    """The engine's jitted paged decode step ``(params, pool, tables, tok,
+    pos, wblk, woff) -> (logits, pool)``: each layer reads its pages in
+    place (``model.decode_step_paged``) and the new tokens are written
+    once, into the donated pool (``kv_cache.write_tokens``)."""
+    def paged_step(params, pool, tables, tok, pos, wblk, woff):
+        logits, new = M.decode_step_paged(cfg, params, tok, pool, tables,
+                                          pos, plan=plan)
+        return logits, KV.write_tokens(pool, new, wblk, woff)
+
+    return jax.jit(paged_step, donate_argnums=1)
+
+
 class ServeEngine:
     """Fixed-slot continuous batching engine (single host).
 
@@ -216,7 +229,7 @@ class ServeEngine:
 
         self.stats = {
             "prefills": 0, "decode_steps": 0, "tokens": 0,
-            "replans": 0,
+            "replans": 0, "paged_inplace_steps": 0,
             "bucket_admissions": {},
             "ftl_schedule": (self.block_plan.schedule
                              if self.block_plan else "n/a"),
@@ -262,7 +275,8 @@ class ServeEngine:
                 return
             g_stat = reg.gauge("serve_stats",
                                "ServeEngine.stats re-expressed", ("stat",))
-            for k in ("prefills", "decode_steps", "tokens", "replans"):
+            for k in ("prefills", "decode_steps", "tokens", "replans",
+                      "paged_inplace_steps"):
                 g_stat.labels(stat=k).set(eng.stats[k])
             g_pc = reg.gauge("serve_plan_cache",
                              "serving PlanCache counters", ("field",))
@@ -295,17 +309,9 @@ class ServeEngine:
     # plan-aware step builders
     # ------------------------------------------------------------------
     def _build_decode(self, plan):
-        base = S.make_decode_step(self.cfg, None, plan=plan)
-        if not self.paged:
-            return jax.jit(base)
-
-        def paged_step(params, pool, tables, tok, pos, wblk, woff):
-            dense = KV.gather_dense(pool, tables)
-            logits, new_dense = base(params, dense, tok, pos)
-            return logits, KV.scatter_token(pool, new_dense, pos, wblk,
-                                            woff)
-
-        return jax.jit(paged_step)
+        if self.paged:
+            return make_paged_step(self.cfg, plan)
+        return jax.jit(S.make_decode_step(self.cfg, None, plan=plan))
 
     def prefill_fn(self, bucket: int, plan):
         """The engine's jitted prefill step for ``bucket`` (built once);
@@ -350,7 +356,8 @@ class ServeEngine:
     def warmup_compile(self, extras: dict[str, Any] | None = None) -> None:
         """Compile every bucket's prefill step and the decode step ahead
         of time, so open-loop latency percentiles measure serving, not
-        XLA compiles.  Pure: engine state is untouched."""
+        XLA compiles.  Engine state is untouched but for the paged
+        pool's scratch page, which the warm-up decode step writes."""
         extras = extras or {}
         for b in self.buckets:
             _, plan = self.plans.get(b, "prefill")
@@ -361,9 +368,11 @@ class ServeEngine:
         if self.paged:
             pos = jnp.zeros((self.slots,), jnp.int32)
             zero = jnp.zeros((self.slots,), jnp.int32)
+            # every slot writes the scratch page; the pool is donated
             out = self.decode_fn(self.params, self.kv.pool,
-                                  self.kv.table_array(), tok, pos, zero,
-                                  zero)
+                                 self.kv.table_array(), tok, pos, zero,
+                                 zero)
+            self.kv.pool = out[1]
         else:
             pos = (jnp.zeros((self.slots,), jnp.int32) if self._vector_pos
                    else jnp.int32(0))
@@ -612,6 +621,8 @@ class ServeEngine:
                     r.done = True
                     r.t_done = now
             self.stats["decode_steps"] += 1
+            if self.paged:
+                self.stats["paged_inplace_steps"] += 1
         finally:
             obslib.end()  # the open serve:step:* phase
             obslib.end()  # serve:decode_step

@@ -246,6 +246,22 @@ def masked_decode_attention(
     return o.reshape(b, hq, 1, dh).astype(q.dtype)
 
 
+def _decode_qkv(cfg, p: Params, x: jax.Array, pos: jax.Array, *,
+                use_rope: bool = True):
+    """One token per row projected to q ``(B, 1, H, Dh)`` and the new k, v
+    ``(B, 1, Hk, Dh)``; q and k roped at the row's absolute position
+    (``pos`` () or ``(B,)``)."""
+    q = _split_heads(linear(p["wq"], x), cfg.n_heads)
+    k = _split_heads(linear(p["wk"], x), cfg.n_kv_heads)
+    v = _split_heads(linear(p["wv"], x), cfg.n_kv_heads)
+    if use_rope:
+        # (B, 1) positions rope each row at its own absolute position
+        posv = pos[:, None] if pos.ndim == 1 else pos[None]
+        q = rope(q, posv, cfg.rope_theta)
+        k = rope(k, posv, cfg.rope_theta)
+    return q, k, v
+
+
 def attention_decode(
     cfg,
     p: Params,
@@ -263,27 +279,21 @@ def attention_decode(
     uniform-length path) or a ``(B,)`` vector for continuous batching at
     mixed sequence lengths: each row writes its new KV at its own
     position and masks its own prefix."""
-    h, hk, dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    h, dh = cfg.n_heads, cfg.resolved_head_dim
     b = x.shape[0]
-    q = _split_heads(linear(p["wq"], x), h)          # (B, 1, H, Dh)
 
     if cross:
         # cross-attention: K/V precomputed at prefill, no rope, no update
+        q = _split_heads(linear(p["wq"], x), h)      # (B, 1, H, Dh)
         k, v = cache["k"], cache["v"]
         mask = jnp.ones((k.shape[1],), bool)
         o = masked_decode_attention(q.transpose(0, 2, 1, 3), k, v, mask)
         o = o.transpose(0, 2, 1, 3).reshape(b, 1, h * dh)
         return linear(p["wo"], o), cache
 
-    k_new = _split_heads(linear(p["wk"], x), hk)     # (B, 1, Hk, Dh)
-    v_new = _split_heads(linear(p["wv"], x), hk)
     pos = jnp.asarray(pos)
     vec = pos.ndim == 1
-    if use_rope:
-        # (B, 1) positions rope each row at its own absolute position
-        posv = pos[:, None] if vec else pos[None]
-        q = rope(q, posv, cfg.rope_theta)
-        k_new = rope(k_new, posv, cfg.rope_theta)
+    q, k_new, v_new = _decode_qkv(cfg, p, x, pos, use_rope=use_rope)
 
     def _dus_rows(full, upd, starts):
         # per-row dynamic update: row i writes its (1, Hk, Dh) slice at
@@ -332,6 +342,42 @@ def attention_decode(
     o = masked_decode_attention(q.transpose(0, 2, 1, 3), k, v, mask)
     o = o.transpose(0, 2, 1, 3).reshape(b, 1, h * dh)
     return linear(p["wo"], o), {"k": k, "v": v}
+
+
+def paged_decode_attention(
+    cfg,
+    p: Params,
+    x: jax.Array,                  # (B, 1, D)
+    pages: Params,                 # {"k", "v"}: (P, bs, Hk, Dh) page pool
+    blocks: jax.Array,             # (B, W) int32 pages of each row, in order
+    pos: jax.Array,                # (B,) int32 absolute positions
+) -> tuple[jax.Array, Params]:
+    """One-token full-attention decode that reads a page pool in place.
+
+    Row ``b`` attends to its cached positions ``< pos[b]``, read from
+    pages ``blocks[b]`` where they lie (``ops.paged_attention``), and to
+    its own new key, which enters the softmax directly: nothing is
+    written here.  The arithmetic is :func:`masked_decode_attention`'s:
+    bf16 inputs, f32 scores, a max-subtracted softmax accumulated in f32.
+    Returns the output ``(B, 1, D)`` and the new token's ``{"k", "v"}:
+    (B, Hk, Dh)`` for the caller to store."""
+    h, hk, dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    b = blocks.shape[0]
+    q, k_new, v_new = _decode_qkv(cfg, p, x, pos)
+    q, k_new, v_new = q[:, 0], k_new[:, 0], v_new[:, 0]
+    acc, m, l = ops.paged_attention(q, pages["k"], pages["v"], blocks, pos)
+    kv_head = jnp.arange(h) // (h // hk)            # of each query head
+    kn, vn = k_new[:, kv_head], v_new[:, kv_head]   # (B, H, Dh)
+    s_new = jnp.einsum("bqd,bqd->bq", q, kn,
+                       preferred_element_type=jnp.float32) * dh ** -0.5
+    m_all = jnp.maximum(m, s_new)
+    alpha, e_new = jnp.exp(m - m_all), jnp.exp(s_new - m_all)
+    o = (alpha[..., None] * acc
+         + (e_new.astype(vn.dtype).astype(jnp.float32)[..., None]
+            * vn.astype(jnp.float32)))
+    o = o / (alpha * l + e_new)[..., None]
+    o = o.astype(q.dtype).reshape(b, 1, h * dh)
+    return linear(p["wo"], o), {"k": k_new, "v": v_new}
 
 
 def init_kv_cache(cfg, batch: int, seq: int, dtype, *, window: int | None = None

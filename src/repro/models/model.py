@@ -49,6 +49,7 @@ from repro.models.layers import (
     linear,
     mlp_layer,
     norm,
+    paged_decode_attention,
 )
 from repro.models.moe import init_moe, moe_layer
 
@@ -612,6 +613,60 @@ def decode_step(cfg, params: Params, token: jax.Array, cache: Params,
             new_cache["rem"][f"rem{i}"] = c
     x = norm(params["final_norm"], x, cfg.norm)
     return _unembed(cfg, params, x), new_cache
+
+
+def _layer_decode_paged(cfg, p: Params, x, pages: Params, blocks, pos,
+                        plan=None):
+    """One full-attention layer's step over its pages.  Returns (x, the
+    new token's {"k", "v"})."""
+    h = norm(p["ln1"], x, cfg.norm)
+    o, new = paged_decode_attention(cfg, p["attn"], h, pages, blocks, pos)
+    x = x + o
+    d, _ = _apply_ffn(cfg, p, x, plan=plan)
+    return constrain(x + d, "residual"), new
+
+
+def decode_step_paged(cfg, params: Params, token: jax.Array, pool: Params,
+                      tables: jax.Array, pos: jax.Array, *, plan=None
+                      ) -> tuple[jax.Array, Params]:
+    """One decode step that reads a paged KV pool in place.
+
+    ``pool`` is ``init_cache(cfg, n_pages, bs)``, its batch axis the page
+    id: stacked leaves ``(L, n_pages, bs, Hk, Dh)``, remainder leaves
+    ``(n_pages, bs, Hk, Dh)``.  ``tables`` ``(B, W)`` holds each row's
+    page ids, ``pos`` ``(B,)`` its position; pure-'attn' decoder-only
+    configs only.  Each layer reads its own pages inside the layer scan.
+    Returns the logits and the step's new K/V in the pool's tree: stacked
+    leaves ``(L, B, Hk, Dh)``, remainder ``(B, Hk, Dh)``; storing them is
+    the caller's."""
+    kinds, n_full, rem_kinds = _layer_split(cfg)
+    x = constrain(_embed(cfg, params["embed"], token), "residual")
+    n_pages = jax.tree.leaves(pool)[0].shape[-4]
+    # (L, n_pages, ...) -> (L * n_pages, ...), a free reshape: layer l's
+    # page j is row l * n_pages + j, so one take reads a layer's pages
+    stacked = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]),
+                           pool["layers"])
+
+    def body(h, inp):
+        pp, layer = inp
+        new = {}
+        for i in range(len(kinds)):
+            h, new[f"pos{i}"] = _layer_decode_paged(
+                cfg, pp[f"pos{i}"], h, stacked[f"pos{i}"],
+                tables + layer * n_pages, pos, plan=plan)
+        return h, new
+
+    x, new_layers = jax.lax.scan(
+        body, x, (params["layers"], jnp.arange(n_full)))
+    new_kv: Params = {"layers": new_layers}
+    if rem_kinds:
+        new_kv["rem"] = {}
+        for i in range(len(rem_kinds)):
+            x, new_kv["rem"][f"rem{i}"] = _layer_decode_paged(
+                cfg, params["rem"][f"rem{i}"], x, pool["rem"][f"rem{i}"],
+                tables, pos, plan=plan)
+    x = norm(params["final_norm"], x, cfg.norm)
+    return _unembed(cfg, params, x), new_kv
 
 
 # ===========================================================================

@@ -12,6 +12,7 @@ import pytest
 from repro.core import hw
 from repro.kernels import flash_attention as _fa, fused_mlp as _fm, gemm as _g
 from repro.kernels import gemm_gelu as _ga, mlstm as _ml, ref, rg_lru as _rg
+from repro.kernels import paged_attention as _pa
 from util import check, run_with_devices
 
 # the kernels take the scoped-VMEM limit of the target they were planned
@@ -23,6 +24,7 @@ gemm = functools.partial(_g.gemm, **_LIMIT)
 gemm_act = functools.partial(_ga.gemm_act, **_LIMIT)
 mlstm_scan = functools.partial(_ml.mlstm_scan, **_LIMIT)
 rg_lru_scan = functools.partial(_rg.rg_lru_scan, **_LIMIT)
+paged_attention = functools.partial(_pa.paged_attention, **_LIMIT)
 
 
 def rnd(key, shape, dtype, scale=1.0):
@@ -150,6 +152,32 @@ def test_flash_attention_cross_q_offset():
                           block_q=128, block_k=128, interpret=True)
     expect = ref.attention(q, k, v, causal=True, q_offset=384)
     np.testing.assert_allclose(out, expect, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("hq,hk,bs", [(8, 4, 8), (4, 2, 8), (4, 1, 16)])
+def test_paged_attention_matches_ref(hq, hk, bs, dtype):
+    """Rows with nothing cached, part of a page, several chunks and the
+    whole table, over pages scattered through the pool in no order."""
+    width, dh = 32, 128
+    lengths = jnp.array([0, 5, 8 * bs + 3, width * bs], jnp.int32)
+    b = lengths.shape[0]
+    n_pages = b * width + 1
+    assert _pa.supported(bs, hk, dtype)
+    blocks = jax.random.permutation(jax.random.PRNGKey(3), n_pages)[
+        :b * width].reshape(b, width).astype(jnp.int32)
+    q = rnd(0, (b, hq, dh), dtype)
+    k = rnd(1, (n_pages, bs, hk, dh), dtype)
+    v = rnd(2, (n_pages, bs, hk, dh), dtype)
+    acc, m, l = paged_attention(q, k, v, blocks, lengths, interpret=True)
+    racc, rm, rl = ref.paged_attention(q, k, v, blocks, lengths)
+    t = tol(dtype)
+    live = lengths > 0
+    np.testing.assert_allclose(m[live], rm[live], **t)
+    np.testing.assert_allclose((acc / l[..., None])[live],
+                               (racc / rl[..., None])[live], **t)
+    # the dead row holds nothing: no weight, no output
+    assert float(l[0].max()) == 0.0 and float(abs(acc[0]).max()) == 0.0
 
 
 # ---------------------------------------------------------------------------

@@ -336,3 +336,126 @@ def test_clear_plan_caches_resets_serve_counters(setup):
     c = eng.plans.counters()
     assert c["plans"] > 0 and c["misses"] > 0
     assert c["misses_after_warmup"] == 0
+
+
+# ---------------------------------------------------------------------------
+# the paged decode step reads the pool in place and writes only the new
+# tokens
+# ---------------------------------------------------------------------------
+
+# (KV heads, dtype) over the reduced llama's 4 query heads: GQA groups of
+# two, MQA, and GQA in the serving precision
+_PAGED_CASES = {"gqa": (2, "float32"), "mqa": (1, "float32"),
+                "gqa_bf16": (2, "bfloat16")}
+_PAGED_ATOL = {"float32": 1e-4, "bfloat16": 6.25e-2}
+
+
+@pytest.fixture(scope="module", params=sorted(_PAGED_CASES))
+def paged_case(request):
+    kv_heads, dtype = _PAGED_CASES[request.param]
+    cfg = dataclasses.replace(configs.get_config("llama3.2-3b").reduced(),
+                              remat=False, n_kv_heads=kv_heads, dtype=dtype)
+    return cfg, M.init_params(cfg, jax.random.PRNGKey(1))
+
+
+def _one_paged_step(cfg, params, prompt_lens: dict[int, int], slots: int):
+    """Prefill each ``slot: prompt length`` of ``prompt_lens`` into a
+    paged engine's pool and into a dense per-slot cache (the same prefill
+    cache in both), then run one decode step each way; slots left out are
+    dead.  Returns (paged logits, dense logits, dense cache after the
+    step, pool before, pool after, positions, write coordinates)."""
+    max_seq = 32
+    eng = ServeEngine(cfg, params, batch_slots=slots, max_seq=max_seq,
+                      block_size=8, eos_id=-1)
+    rng = np.random.default_rng(12)
+    dense = M.init_cache(cfg, slots, max_seq)
+    tok = np.zeros((slots, 1), np.int32)
+    pos = np.zeros(slots, np.int32)
+    wblk = np.zeros(slots, np.int32)
+    woff = np.zeros(slots, np.int32)
+    for i, n in prompt_lens.items():
+        prompt = rng.integers(2, cfg.vocab_size, size=n).astype(np.int32)
+        bucket, plan = eng.plans.get(n, "prefill")
+        padded = np.zeros(bucket, np.int32)
+        padded[:n] = prompt
+        _, cache1 = eng.prefill_fn(bucket, plan)(
+            params, {"tokens": jnp.asarray(padded)[None]}, jnp.int32(n - 1))
+        eng.kv.write_prefill(i, cache1, bucket)
+        dense = jax.tree.map(
+            lambda d, c: d.at[:, i, :bucket].set(c[:, 0]), dense, cache1)
+        tok[i, 0] = rng.integers(2, cfg.vocab_size)
+        pos[i] = n
+        assert eng.kv.allocate(i, n + 1)
+        wblk[i], woff[i] = eng.kv.write_coords(i, n)
+    before = jax.tree.map(np.asarray, eng.kv.pool)
+    logits, pool = eng.decode_fn(
+        params, eng.kv.pool, eng.kv.table_array(), jnp.asarray(tok),
+        jnp.asarray(pos), jnp.asarray(wblk), jnp.asarray(woff))
+    after = jax.tree.map(np.asarray, pool)
+    dlogits, dense = M.decode_step(cfg, params, jnp.asarray(tok), dense,
+                                   jnp.asarray(pos))
+    return (np.asarray(logits, np.float32), np.asarray(dlogits, np.float32),
+            dense, before, after, pos, (wblk, woff))
+
+
+# slots 0, 1, 3 at positions 5, 8 (this step opens the slot's second
+# page) and 13; slot 2 dead
+_MIXED = {0: 5, 1: 8, 3: 13}
+
+
+def test_paged_inplace_step_matches_dense_logits(paged_case):
+    """One step of the in-place paged decode against the dense per-slot
+    path on the same prefill caches: the live slots' logits agree and
+    pick the same tokens, for GQA and MQA, in float32 and bfloat16."""
+    cfg, params = paged_case
+    logits, dlogits, _, _, _, _, _ = _one_paged_step(cfg, params, _MIXED, 4)
+    live = sorted(_MIXED)
+    atol = _PAGED_ATOL[cfg.dtype]
+    np.testing.assert_allclose(logits[live], dlogits[live], atol=atol,
+                               rtol=0)
+    assert (logits[live, 0].argmax(-1) == dlogits[live, 0].argmax(-1)).all()
+    assert np.isfinite(logits).all()      # the dead slot too
+
+
+def test_paged_inplace_step_writes_only_the_new_tokens(paged_case):
+    """After one step each live slot's new K/V sits at its (block,
+    offset), equal to what the dense path appended at its position;
+    every other row of every mapped page is bitwise unchanged, and the
+    dead slot touched nothing but the scratch page 0."""
+    cfg, params = paged_case
+    _, _, dense, before, after, pos, (wblk, woff) = _one_paged_step(
+        cfg, params, _MIXED, 4)
+    live = sorted(_MIXED)
+    assert wblk[2] == 0 and woff[2] == 0
+    assert woff[1] == 0 and wblk[1] not in (0, wblk[0], wblk[3])
+    written = {(int(wblk[i]), int(woff[i])) for i in live}
+    atol = _PAGED_ATOL[cfg.dtype]
+    for name in ("k", "v"):
+        b, a = before["layers"]["pos0"][name], after["layers"]["pos0"][name]
+        d = np.asarray(dense["layers"]["pos0"][name], np.float32)
+        assert a.shape == b.shape            # (L, NB+1, bs, Hk, Dh)
+        changed = (a != b).any(axis=(0, 3, 4))              # (NB+1, bs)
+        for blk, off in zip(*np.nonzero(changed)):
+            assert blk == 0 or (blk, off) in written, (name, blk, off)
+        for i in live:
+            got = a[:, wblk[i], woff[i]].astype(np.float32)     # (L, Hk, Dh)
+            np.testing.assert_allclose(got, d[:, i, pos[i]], atol=atol,
+                                       rtol=0)
+
+
+def test_paged_engine_counts_inplace_steps(setup):
+    """Every decode step of a paged engine runs the in-place step; the
+    dense per-slot engine runs none."""
+    cfg, params = setup
+    rng = np.random.default_rng(13)
+    reqs = lambda: [Request(i, rng.integers(2, cfg.vocab_size, size=5 + i)  # noqa: E731
+                            .astype(np.int32), 4) for i in range(3)]
+    eng = ServeEngine(cfg, params, batch_slots=2, max_seq=32, eos_id=-1)
+    eng.run(reqs(), {})
+    assert eng.paged and eng.stats["decode_steps"] > 0
+    assert eng.stats["paged_inplace_steps"] == eng.stats["decode_steps"]
+    eng_d = ServeEngine(cfg, params, batch_slots=2, max_seq=32,
+                        paged=False, eos_id=-1)
+    eng_d.run(reqs(), {})
+    assert eng_d.stats["decode_steps"] > 0
+    assert eng_d.stats["paged_inplace_steps"] == 0

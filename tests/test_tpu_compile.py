@@ -7,6 +7,9 @@ FTL planner picks for yi-6b (and the recurrent kernels at the widths of
 recurrentgemma-9b and xlstm-1.3b) and compile it against a described
 ``v5e:2x2`` topology — the chip's compiler, with no chip attached.
 
+The engine's paged decode step compiles whole, at yi-6b widths and two
+layers, to check what its compiled program does with the KV pool.
+
 The planned kernels compile under a limit of the planner's own footprint
 estimate plus ``hw.PALLAS_VMEM_HEADROOM``, tighter than the target's
 ``vmem_limit_bytes`` they ask for in use: Mosaic's internal scratch comes
@@ -17,7 +20,11 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler library, and each test
 worker imports every test file.
 """
+import dataclasses
+import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +39,7 @@ from repro.kernels.fused_mlp import fused_mlp
 from repro.kernels.gemm import gemm
 from repro.kernels.gemm_gelu import gemm_act
 from repro.kernels.mlstm import mlstm_scan
+from repro.kernels.paged_attention import paged_attention
 from repro.kernels.rg_lru import rg_lru_scan
 
 TARGET = hw.TPU_V5E
@@ -152,3 +160,71 @@ def test_mlstm_compiles_at_xlstm13b_width(one_chip):
     _compile(lambda q, k, v, i, f: mlstm_scan(
         q, k, v, i, f, vmem_limit_bytes=TARGET.vmem_limit_bytes),
         one_chip, qkv, qkv, qkv, gate, gate)
+
+
+def _hlo_arrays(text: str):
+    """(opcode, dims) of every array-shaped instruction of an HLO dump."""
+    pat = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+    for line in text.splitlines():
+        m = pat.match(line)
+        if m:
+            yield m.group(2), tuple(int(d) for d in m.group(1).split(",")
+                                    if d)
+
+
+@pytest.mark.parametrize("slots,max_seq", [(10, 2048), (4, 4096)])
+def test_paged_attention_compiles_at_yi6b_width(one_chip, slots, max_seq):
+    hq, hk, dh, bs = YI.n_heads, YI.n_kv_heads, YI.resolved_head_dim, 8
+    n_pages = slots * max_seq // bs + 1
+    _compile(lambda q, k, v, blocks, lens: paged_attention(
+        q, k, v, blocks, lens, vmem_limit_bytes=TARGET.vmem_limit_bytes),
+        one_chip, ((slots, hq, dh), BF16), ((n_pages, bs, hk, dh), BF16),
+        ((n_pages, bs, hk, dh), BF16), ((slots, max_seq // bs), jnp.int32),
+        ((slots,), jnp.int32))
+
+
+def test_paged_decode_step_reads_the_pool_in_place(one_chip, monkeypatch):
+    """The engine's paged decode step at yi-6b widths (2 layers, 10 slots
+    at max_seq 2048, 8-token pages), traced as on the chip, runs the
+    paged-attention kernel and takes the pool donated and in place:
+    every pool byte aliases an output, no copy is as large as a pool
+    leaf, and no instruction holds a dense per-slot view of the cache."""
+    from repro.launch.serve import make_paged_step
+    from repro.models import model as M
+
+    # the step picks the kernel by the backend it is traced for
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    slots, max_seq, bs = 10, 2048, 8
+    cfg = dataclasses.replace(YI, n_layers=2, ftl_mode="auto")
+    _, plan = M.serve_plan(cfg, m=1, dtype=cfg.dtype, target=TARGET,
+                           phase="decode")
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.eval_shape(M.init_params, cfg, jax.random.PRNGKey(0))
+    pool = jax.eval_shape(functools.partial(
+        M.init_cache, cfg, slots * max_seq // bs + 1, bs))
+    ints = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+            for s in ((slots, max_seq // bs), (slots, 1), (slots,),
+                      (slots,), (slots,))]
+    compiled = make_paged_step(cfg, plan).lower(
+        jax.tree.map(spec, params), jax.tree.map(spec, pool),
+        *ints).compile()
+
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert "%paged_attention" in text
+    leaves = jax.tree.leaves(pool)
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in leaves)
+    leaf_size = min(a.size for a in leaves)
+    hk, dh = cfg.n_kv_heads, cfg.resolved_head_dim
+    dense = (slots, max_seq, hk, dh)
+    dense_size = cfg.n_layers * math.prod(dense)
+    for op, dims in _hlo_arrays(text):
+        if op.startswith("copy"):
+            assert math.prod(dims) < leaf_size, (op, dims)
+        assert dims[-4:] != dense, (op, dims)
+        assert math.prod(dims) != dense_size, (op, dims)
