@@ -329,16 +329,23 @@ def attention(q, k, v, *, causal: bool = True, window: int | None = None,
     return _per_shard(body, mesh, (q, k, v), (spec,) * 3, spec)
 
 
+def paged_kernel(block_size: int, kv_heads: int, dtype,
+                 backend: Backend = "auto") -> bool:
+    """Whether :func:`paged_attention` runs the Pallas kernel over pages
+    of ``block_size`` positions of ``kv_heads`` heads in ``dtype``."""
+    return _resolve(backend) != "ref" and _paged.supported(
+        block_size, kv_heads, dtype)
+
+
 def paged_attention(q, k_pages, v_pages, blocks, lengths, *,
                     backend: Backend = "auto",
                     target: hwlib.Target | None = None):
     """Decode attention over KV pages read in place: ``(acc, m, l)`` of
     each row's cached positions (``ref.paged_attention``).  The Pallas
-    kernel where the pages fit its tiles (``paged_attention.supported``),
-    the jnp gather otherwise."""
+    kernel where the pages fit its tiles (``paged_kernel``), the jnp
+    gather otherwise."""
     bs, hk = k_pages.shape[1], k_pages.shape[2]
-    if _resolve(backend) == "ref" or not _paged.supported(bs, hk,
-                                                          k_pages.dtype):
+    if not paged_kernel(bs, hk, k_pages.dtype, backend):
         return _ref.paged_attention(q, k_pages, v_pages, blocks, lengths)
     return _paged.paged_attention(
         q, k_pages, v_pages, blocks, lengths,

@@ -38,13 +38,27 @@ def _chunk(width: int) -> int:
     return c
 
 
+def _sublanes(dtype) -> int:
+    """Rows of one VMEM tile of ``dtype``: 8 of 32 bits, packed."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
 def supported(block_size: int, kv_heads: int, dtype) -> bool:
     """Whether a page's ``block_size * kv_heads`` rows fill whole VMEM
     tiles of ``dtype`` and the head index is a bit field (a power-of-two
     ``kv_heads``): the shapes this kernel runs."""
-    sublanes = 8 * 4 // jnp.dtype(dtype).itemsize
-    return (block_size * kv_heads) % sublanes == 0 and \
+    return (block_size * kv_heads) % _sublanes(dtype) == 0 and \
         kv_heads & (kv_heads - 1) == 0
+
+
+def block_size_for(kv_heads: int, dtype) -> int:
+    """The shortest page, a multiple of 8 positions, whose ``block_size *
+    kv_heads`` rows fill whole VMEM tiles of ``dtype``: 8 for GQA with 2
+    or more KV heads, 16 for one KV head in bf16."""
+    bs = 8
+    while (bs * kv_heads) % _sublanes(dtype):
+        bs += 8
+    return bs
 
 
 def _make_kernel(*, bs: int, hk: int, group: int, chunk: int, scale: float):

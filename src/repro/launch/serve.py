@@ -44,6 +44,8 @@ from repro import obs as obslib
 from repro.configs import get_config
 from repro.core import hw
 from repro.core.ftl import registry as ftl_registry
+from repro.kernels import ops
+from repro.kernels.paged_attention import block_size_for
 from repro.launch import kv_cache as KV
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
@@ -165,14 +167,22 @@ class ServeEngine:
     """Fixed-slot continuous batching engine (single host).
 
     ``target`` picks the planning preset (None → the process default);
-    ``block_size`` is the paged-KV page length (``paged=False`` forces
-    the dense per-slot cache, ``kv_blocks`` shrinks the physical pool
-    below ``slots * max_seq / block_size`` to exercise admission
-    control)."""
+    ``block_size`` is the paged-KV page length (None → the shortest whose
+    pages fill the paged-attention kernel's tiles, ``block_size_for``: 8
+    positions for GQA, 16 for MQA in bf16); ``paged=False`` forces the
+    dense per-slot cache, ``kv_blocks`` shrinks the physical pool below
+    ``slots * max_seq / block_size`` to exercise admission control.
+
+    Besides the step counts, ``stats`` counts the decode steps whose
+    attention ran the Pallas paged kernel (``paged_kernel_steps``) and
+    the KV positions of the whole pages those steps had to read
+    (``paged_page_positions``: each slot's cached positions rounded up to
+    whole pages, summed over the steps)."""
 
     def __init__(self, cfg, params, *, batch_slots: int, max_seq: int,
                  eos_id: int = 1, target: hw.Target | None = None,
-                 block_size: int = 8, paged: bool | None = None,
+                 block_size: int | None = None,
+                 paged: bool | None = None,
                  kv_blocks: int | None = None,
                  buckets: tuple[int, ...] | None = None,
                  obs: bool = False,
@@ -184,6 +194,8 @@ class ServeEngine:
         self.max_seq = max_seq
         self.eos = eos_id
         self.target = target if target is not None else hw.default_target()
+        if block_size is None:
+            block_size = block_size_for(cfg.n_kv_heads, cfg.dtype)
         self.block_size = block_size
         self.buckets = (tuple(buckets) if buckets is not None
                         else _default_buckets(max_seq, block_size))
@@ -215,6 +227,10 @@ class ServeEngine:
         else:
             self.kv = None
             self.cache = M.init_cache(cfg, batch_slots, max_seq)
+        # whether the paged step's attention is the Pallas kernel (decided
+        # as ops.paged_attention decides it when the step is traced)
+        self.paged_kernel = self.paged and ops.paged_kernel(
+            block_size, cfg.n_kv_heads, cfg.dtype)
 
         # AOT warmup of the bucket ladder + the decode plan: after this,
         # steady state never plans again (the bench gate).
@@ -230,6 +246,7 @@ class ServeEngine:
         self.stats = {
             "prefills": 0, "decode_steps": 0, "tokens": 0,
             "replans": 0, "paged_inplace_steps": 0,
+            "paged_kernel_steps": 0, "paged_page_positions": 0,
             "bucket_admissions": {},
             "ftl_schedule": (self.block_plan.schedule
                              if self.block_plan else "n/a"),
@@ -239,9 +256,11 @@ class ServeEngine:
         ftl_registry.register_counter_reset(self)
 
         # telemetry (repro.obs): span recording + per-step gauges + the
-        # online drift monitor, all opt-in — a bare engine pays nothing.
+        # online drift monitor, all opt-in — a bare engine pays nothing
+        # but its counters, read only when the registry is collected.
         self.obs = bool(obs)
         self.drift = None
+        self._register_collector()
         if self.obs:
             obslib.enable()
             self.drift = obslib.DriftMonitor(
@@ -261,12 +280,12 @@ class ServeEngine:
                 "serve_evictions_total", "slots freed (EOS/max-len)")
             self._h_step = obslib.histogram(
                 "serve_decode_step_seconds", "wall-clock per decode step")
-            self._register_obs_collector()
 
-    def _register_obs_collector(self) -> None:
-        """Re-express ``plan_report()``/``stats`` on the metrics registry
-        at collect time.  Weakly bound: a dead engine's collector is a
-        no-op, never a leak."""
+    def _register_collector(self) -> None:
+        """Re-express ``stats`` (every engine) and ``plan_report()``
+        (obs-enabled engines) on the metrics registry at collect time.
+        Weakly bound: a dead engine's collector is a no-op, never a
+        leak."""
         ref = weakref.ref(self)
 
         def _collect(reg) -> None:
@@ -276,8 +295,11 @@ class ServeEngine:
             g_stat = reg.gauge("serve_stats",
                                "ServeEngine.stats re-expressed", ("stat",))
             for k in ("prefills", "decode_steps", "tokens", "replans",
-                      "paged_inplace_steps"):
+                      "paged_inplace_steps", "paged_kernel_steps",
+                      "paged_page_positions"):
                 g_stat.labels(stat=k).set(eng.stats[k])
+            if not eng.obs:
+                return
             g_pc = reg.gauge("serve_plan_cache",
                              "serving PlanCache counters", ("field",))
             for k, v in eng.plans.counters().items():
@@ -589,6 +611,10 @@ class ServeEngine:
                         wblk[i], woff[i] = self.kv.write_coords(
                             i, int(self.pos[i]))
                     # dead slots keep (0, 0): the scratch page
+                # the kernel reads the whole pages of each slot's cached
+                # positions (none for an empty slot, at position 0)
+                bs = self.block_size
+                page_positions = int(np.sum(-(-self.pos // bs))) * bs
                 args = (self.params, self.kv.pool, self.kv.table_array(),
                         jnp.asarray(tok), jnp.asarray(self.pos),
                         jnp.asarray(wblk), jnp.asarray(woff))
@@ -623,6 +649,9 @@ class ServeEngine:
             self.stats["decode_steps"] += 1
             if self.paged:
                 self.stats["paged_inplace_steps"] += 1
+            if self.paged_kernel:
+                self.stats["paged_kernel_steps"] += 1
+                self.stats["paged_page_positions"] += page_positions
         finally:
             obslib.end()  # the open serve:step:* phase
             obslib.end()  # serve:decode_step
@@ -744,8 +773,10 @@ def main() -> None:
                     choices=["off", "fused", "scan", "auto"],
                     help="executor mode (default: the config's); 'auto' "
                     "runs the planned bindings, Pallas kernels on TPU")
-    ap.add_argument("--block-size", type=int, default=8,
-                    help="paged-KV page length in tokens")
+    ap.add_argument("--block-size", type=int, default=None,
+                    help="paged-KV page length in tokens (default: the "
+                    "shortest whose pages fill the paged-attention "
+                    "kernel's tiles)")
     ap.add_argument("--dense-kv", action="store_true",
                     help="force the dense per-slot cache")
     ap.add_argument("--arrival-rate", type=float, default=None,

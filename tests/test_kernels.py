@@ -180,6 +180,36 @@ def test_paged_attention_matches_ref(hq, hk, bs, dtype):
     assert float(l[0].max()) == 0.0 and float(abs(acc[0]).max()) == 0.0
 
 
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_paged_attention_mqa_at_the_engines_page_length(dtype):
+    """granite's 48 query heads over one KV head, at the page length the
+    serving engine picks for MQA (16 positions in bf16, 8 in f32), over
+    a table as wide as a 2048-position slot: slots at mixed lengths, one
+    within its first page, one ending a page, one over several chunks,
+    one full, and a dead slot between them."""
+    hq, hk, dh = 48, 1, 128
+    bs = _pa.block_size_for(hk, dtype)
+    assert bs == (16 if dtype == jnp.bfloat16 else 8)
+    assert _pa.supported(bs, hk, dtype)
+    width = 2048 // bs
+    lengths = jnp.array([7, 2 * bs, 0, 37 * bs + 5, width * bs], jnp.int32)
+    b = lengths.shape[0]
+    n_pages = b * width + 1
+    blocks = jax.random.permutation(jax.random.PRNGKey(5), n_pages)[
+        :b * width].reshape(b, width).astype(jnp.int32)
+    q = rnd(0, (b, hq, dh), dtype)
+    k = rnd(1, (n_pages, bs, hk, dh), dtype)
+    v = rnd(2, (n_pages, bs, hk, dh), dtype)
+    acc, m, l = paged_attention(q, k, v, blocks, lengths, interpret=True)
+    racc, rm, rl = ref.paged_attention(q, k, v, blocks, lengths)
+    t = tol(dtype)
+    live = lengths > 0
+    np.testing.assert_allclose(m[live], rm[live], **t)
+    np.testing.assert_allclose((acc / l[..., None])[live],
+                               (racc / rl[..., None])[live], **t)
+    assert float(l[2].max()) == 0.0 and float(abs(acc[2]).max()) == 0.0
+
+
 # ---------------------------------------------------------------------------
 # recurrent kernels
 # ---------------------------------------------------------------------------

@@ -358,15 +358,17 @@ def paged_case(request):
     return cfg, M.init_params(cfg, jax.random.PRNGKey(1))
 
 
-def _one_paged_step(cfg, params, prompt_lens: dict[int, int], slots: int):
+def _one_paged_step(cfg, params, prompt_lens: dict[int, int], slots: int,
+                    block_size: int | None = 8):
     """Prefill each ``slot: prompt length`` of ``prompt_lens`` into a
-    paged engine's pool and into a dense per-slot cache (the same prefill
-    cache in both), then run one decode step each way; slots left out are
-    dead.  Returns (paged logits, dense logits, dense cache after the
-    step, pool before, pool after, positions, write coordinates)."""
+    paged engine's pool (pages of ``block_size``, None: the engine's
+    pick) and into a dense per-slot cache (the same prefill cache in
+    both), then run one decode step each way; slots left out are dead.
+    Returns (paged logits, dense logits, dense cache after the step, pool
+    before, pool after, positions, write coordinates)."""
     max_seq = 32
     eng = ServeEngine(cfg, params, batch_slots=slots, max_seq=max_seq,
-                      block_size=8, eos_id=-1)
+                      block_size=block_size, eos_id=-1)
     rng = np.random.default_rng(12)
     dense = M.init_cache(cfg, slots, max_seq)
     tok = np.zeros((slots, 1), np.int32)
@@ -459,3 +461,95 @@ def test_paged_engine_counts_inplace_steps(setup):
     eng_d.run(reqs(), {})
     assert eng_d.stats["decode_steps"] > 0
     assert eng_d.stats["paged_inplace_steps"] == 0
+
+
+# ---------------------------------------------------------------------------
+# the page length follows the paged-attention kernel's tiles, and the
+# engine counts the steps and pages the kernel reads
+# ---------------------------------------------------------------------------
+
+from repro.kernels import ops                                # noqa: E402
+from repro.kernels import paged_attention as PA              # noqa: E402
+
+
+@pytest.mark.parametrize("arch,dtype,block_size", [
+    ("yi-6b", "bfloat16", 8),          # GQA: 8 positions x 4 heads fill
+    ("granite-20b", "bfloat16", 16),   # MQA: a bf16 tile is 16 rows
+    ("granite-20b", "float32", 8),     # an f32 tile is 8
+])
+def test_engine_picks_the_shortest_page_that_fills_the_kernels_tiles(
+        arch, dtype, block_size):
+    cfg = dataclasses.replace(configs.get_config(arch).reduced(),
+                              remat=False, dtype=dtype)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServeEngine(cfg, params, batch_slots=2, max_seq=32, eos_id=-1)
+    assert eng.block_size == eng.kv.block_size == block_size
+    assert PA.supported(block_size, cfg.n_kv_heads, dtype)
+    # an explicit page length still wins
+    eng = ServeEngine(cfg, params, batch_slots=2, max_seq=32, eos_id=-1,
+                      block_size=32)
+    assert eng.kv.block_size == 32
+
+
+@pytest.fixture(scope="module")
+def granite_bf16():
+    """granite's block at a CPU size: MQA, un-gated GELU MLP and
+    attention with biases, LayerNorm, in the serving precision."""
+    cfg = dataclasses.replace(configs.get_config("granite-20b").reduced(),
+                              remat=False, dtype="bfloat16")
+    assert cfg.n_kv_heads == 1 and not cfg.mlp_gated and cfg.mlp_bias
+    assert cfg.norm == "layernorm" and cfg.mlp_act == "gelu"
+    return cfg, M.init_params(cfg, jax.random.PRNGKey(2))
+
+
+def test_mqa_gelu_layernorm_engine_matches_dense_at_16_token_pages(
+        granite_bf16):
+    """The engine's own page length for MQA in bf16 (16 positions): one
+    paged step against the dense per-slot path, with slots inside their
+    first page, opening their second (position 16) and deep in it, and
+    a dead slot."""
+    cfg, params = granite_bf16
+    lens = {0: 5, 1: 16, 3: 27}
+    logits, dlogits, _, _, _, _, (wblk, woff) = _one_paged_step(
+        cfg, params, lens, 4, block_size=None)
+    assert woff[1] == 0 and woff[3] == 11        # 16-position pages
+    live = sorted(lens)
+    np.testing.assert_allclose(logits[live], dlogits[live],
+                               atol=_PAGED_ATOL["bfloat16"], rtol=0)
+    assert (logits[live, 0].argmax(-1) == dlogits[live, 0].argmax(-1)).all()
+
+
+def test_paged_engine_counts_kernel_steps_and_page_positions(
+        granite_bf16, monkeypatch):
+    """With the Pallas kernel on the step's path (interpret mode here),
+    every decode step counts as a kernel step, and each adds the whole
+    16-position pages of every slot's cached positions; off it (the jnp
+    gather on the CPU) neither counter moves."""
+    cfg, params = granite_bf16
+
+    def reqs():
+        rng = np.random.default_rng(14)
+        return [Request(i, rng.integers(2, cfg.vocab_size, size=n)
+                        .astype(np.int32), 4) for i, n in enumerate((5, 20))]
+
+    eng = ServeEngine(cfg, params, batch_slots=2, max_seq=64, eos_id=-1)
+    assert not eng.paged_kernel
+    eng.run(reqs(), {})
+    assert eng.stats["decode_steps"] == 3
+    assert eng.stats["paged_kernel_steps"] == 0
+    assert eng.stats["paged_page_positions"] == 0
+
+    monkeypatch.setattr(ops, "_resolve", lambda backend: "pallas")
+    eng = ServeEngine(cfg, params, batch_slots=2, max_seq=64, eos_id=-1)
+    assert eng.paged_kernel and eng.block_size == 16
+    done = {r.rid: r.out for r in eng.run(reqs(), {})}
+    # both admitted at once, then three steps at cached positions 5+k and
+    # 20+k: one page and two
+    assert eng.stats["decode_steps"] == eng.stats["paged_kernel_steps"] == 3
+    assert eng.stats["paged_page_positions"] == 3 * (16 + 32)
+    from repro import obs
+    got = {lbl["stat"]: v for lbl, v in obs.collect()["serve_stats"][
+        "samples"]}
+    assert got["paged_kernel_steps"] == 3
+    assert got["paged_page_positions"] == 3 * (16 + 32)
+    assert all(len(out) == 4 for out in done.values())

@@ -7,8 +7,10 @@ FTL planner picks for yi-6b (and the recurrent kernels at the widths of
 recurrentgemma-9b and xlstm-1.3b) and compile it against a described
 ``v5e:2x2`` topology — the chip's compiler, with no chip attached.
 
-The engine's paged decode step compiles whole, at yi-6b widths and two
-layers, to check what its compiled program does with the KV pool.
+The engine's paged decode step compiles whole, at yi-6b widths and at
+granite-20b's (MQA, 48 query heads over one KV head, 16-token pages)
+with two layers, to check what its compiled program does with the KV
+pool.
 
 The planned kernels compile under a limit of the planner's own footprint
 estimate plus ``hw.PALLAS_VMEM_HEADROOM``, tighter than the target's
@@ -39,11 +41,12 @@ from repro.kernels.fused_mlp import fused_mlp
 from repro.kernels.gemm import gemm
 from repro.kernels.gemm_gelu import gemm_act
 from repro.kernels.mlstm import mlstm_scan
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import block_size_for, paged_attention
 from repro.kernels.rg_lru import rg_lru_scan
 
 TARGET = hw.TPU_V5E
 YI = get_config("yi-6b")
+GRANITE = get_config("granite-20b")
 BF16 = jnp.bfloat16
 
 
@@ -172,15 +175,30 @@ def _hlo_arrays(text: str):
                                     if d)
 
 
-@pytest.mark.parametrize("slots,max_seq", [(10, 2048), (4, 4096)])
-def test_paged_attention_compiles_at_yi6b_width(one_chip, slots, max_seq):
-    hq, hk, dh, bs = YI.n_heads, YI.n_kv_heads, YI.resolved_head_dim, 8
+def _compile_paged_attention(cfg, sharding, slots, max_seq):
+    hq, hk, dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    bs = block_size_for(hk, BF16)
     n_pages = slots * max_seq // bs + 1
     _compile(lambda q, k, v, blocks, lens: paged_attention(
         q, k, v, blocks, lens, vmem_limit_bytes=TARGET.vmem_limit_bytes),
-        one_chip, ((slots, hq, dh), BF16), ((n_pages, bs, hk, dh), BF16),
+        sharding, ((slots, hq, dh), BF16), ((n_pages, bs, hk, dh), BF16),
         ((n_pages, bs, hk, dh), BF16), ((slots, max_seq // bs), jnp.int32),
         ((slots,), jnp.int32))
+
+
+@pytest.mark.parametrize("slots,max_seq", [(10, 2048), (4, 4096)])
+def test_paged_attention_compiles_at_yi6b_width(one_chip, slots, max_seq):
+    assert block_size_for(YI.n_kv_heads, BF16) == 8
+    _compile_paged_attention(YI, one_chip, slots, max_seq)
+
+
+@pytest.mark.parametrize("slots,max_seq", [(256, 2048), (16, 4096)])
+def test_paged_attention_compiles_at_granite20b_width(one_chip, slots,
+                                                      max_seq):
+    """48 query heads over one KV head, 16-token pages, at the slots and
+    contexts of granite's decode-batch and prefill-open cells."""
+    assert block_size_for(GRANITE.n_kv_heads, BF16) == 16
+    _compile_paged_attention(GRANITE, one_chip, slots, max_seq)
 
 
 def test_paged_decode_step_reads_the_pool_in_place(one_chip, monkeypatch):
@@ -189,14 +207,26 @@ def test_paged_decode_step_reads_the_pool_in_place(one_chip, monkeypatch):
     paged-attention kernel and takes the pool donated and in place:
     every pool byte aliases an output, no copy is as large as a pool
     leaf, and no instruction holds a dense per-slot view of the cache."""
+    _check_paged_step(YI, one_chip, monkeypatch, slots=10, max_seq=2048)
+
+
+def test_paged_decode_step_reads_the_pool_in_place_at_granite20b_width(
+        one_chip, monkeypatch):
+    """The same at granite-20b widths (MQA 48/1, GELU MLP with biases,
+    LayerNorm; 2 layers, 256 slots at max_seq 2048, 16-token pages)."""
+    _check_paged_step(GRANITE, one_chip, monkeypatch, slots=256,
+                      max_seq=2048)
+
+
+def _check_paged_step(base, one_chip, monkeypatch, *, slots, max_seq):
     from repro.launch.serve import make_paged_step
     from repro.models import model as M
 
     # the step picks the kernel by the backend it is traced for
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
-    slots, max_seq, bs = 10, 2048, 8
-    cfg = dataclasses.replace(YI, n_layers=2, ftl_mode="auto")
+    cfg = dataclasses.replace(base, n_layers=2, ftl_mode="auto")
+    bs = block_size_for(cfg.n_kv_heads, cfg.dtype)
     _, plan = M.serve_plan(cfg, m=1, dtype=cfg.dtype, target=TARGET,
                            phase="decode")
 
