@@ -153,8 +153,7 @@ KERNEL_OPS = [["jit_paged_step:fusion.3", 0.2],
 
 def test_roofline_is_least_time_of_the_cached_positions_over_kernel_time(
         monkeypatch):
-    _counters(monkeypatch, decode_steps=10, paged_kernel_steps=10,
-              paged_page_positions=480)
+    _counters(monkeypatch, decode_steps=10, paged_kernel_steps=10)
     # each step read one row's cached positions: 100, 101 and 300
     least = [flops.least_time_s(*paged.decode_step(MQA, c, 1), PEAK)
              for c in (100, 101, 300)]
@@ -167,16 +166,12 @@ def test_roofline_is_least_time_of_the_cached_positions_over_kernel_time(
     # a program with no such counters (the gather-only engine)
     ({"decode_steps": 10}, KERNEL_OPS, 3),
     # decode steps that took the jnp gather
-    ({"decode_steps": 10, "paged_kernel_steps": 0,
-      "paged_page_positions": 0}, KERNEL_OPS, 3),
-    ({"decode_steps": 10, "paged_kernel_steps": 9,
-      "paged_page_positions": 432}, KERNEL_OPS, 3),
+    ({"decode_steps": 10, "paged_kernel_steps": 0}, KERNEL_OPS, 3),
+    ({"decode_steps": 10, "paged_kernel_steps": 9}, KERNEL_OPS, 3),
     # the kernel fell out of the trace's listed operations
-    ({"decode_steps": 10, "paged_kernel_steps": 10,
-      "paged_page_positions": 480}, KERNEL_OPS[:1], 3),
+    ({"decode_steps": 10, "paged_kernel_steps": 10}, KERNEL_OPS[:1], 3),
     # the trace's decode programs and the stretch's steps disagree
-    ({"decode_steps": 10, "paged_kernel_steps": 10,
-      "paged_page_positions": 480}, KERNEL_OPS, 6),
+    ({"decode_steps": 10, "paged_kernel_steps": 10}, KERNEL_OPS, 6),
 ])
 def test_roofline_reads_nothing_without_counters_or_a_kernel(
         monkeypatch, stats, ops, programs):
@@ -185,8 +180,7 @@ def test_roofline_reads_nothing_without_counters_or_a_kernel(
 
 
 def test_roofline_reads_nothing_without_a_trace(monkeypatch):
-    _counters(monkeypatch, decode_steps=10, paged_kernel_steps=10,
-              paged_page_positions=480)
+    _counters(monkeypatch, decode_steps=10, paged_kernel_steps=10)
     ctx = _ctx(KERNEL_OPS)
     ctx["trace"] = None
     assert _read(ctx) is None

@@ -174,10 +174,7 @@ class ServeEngine:
     ``slots * max_seq / block_size`` to exercise admission control.
 
     Besides the step counts, ``stats`` counts the decode steps whose
-    attention ran the Pallas paged kernel (``paged_kernel_steps``) and
-    the KV positions of the whole pages those steps had to read
-    (``paged_page_positions``: each slot's cached positions rounded up to
-    whole pages, summed over the steps)."""
+    attention ran the Pallas paged kernel (``paged_kernel_steps``)."""
 
     def __init__(self, cfg, params, *, batch_slots: int, max_seq: int,
                  eos_id: int = 1, target: hw.Target | None = None,
@@ -246,7 +243,7 @@ class ServeEngine:
         self.stats = {
             "prefills": 0, "decode_steps": 0, "tokens": 0,
             "replans": 0, "paged_inplace_steps": 0,
-            "paged_kernel_steps": 0, "paged_page_positions": 0,
+            "paged_kernel_steps": 0,
             "bucket_admissions": {},
             "ftl_schedule": (self.block_plan.schedule
                              if self.block_plan else "n/a"),
@@ -295,8 +292,7 @@ class ServeEngine:
             g_stat = reg.gauge("serve_stats",
                                "ServeEngine.stats re-expressed", ("stat",))
             for k in ("prefills", "decode_steps", "tokens", "replans",
-                      "paged_inplace_steps", "paged_kernel_steps",
-                      "paged_page_positions"):
+                      "paged_inplace_steps", "paged_kernel_steps"):
                 g_stat.labels(stat=k).set(eng.stats[k])
             if not eng.obs:
                 return
@@ -611,10 +607,6 @@ class ServeEngine:
                         wblk[i], woff[i] = self.kv.write_coords(
                             i, int(self.pos[i]))
                     # dead slots keep (0, 0): the scratch page
-                # the kernel reads the whole pages of each slot's cached
-                # positions (none for an empty slot, at position 0)
-                bs = self.block_size
-                page_positions = int(np.sum(-(-self.pos // bs))) * bs
                 args = (self.params, self.kv.pool, self.kv.table_array(),
                         jnp.asarray(tok), jnp.asarray(self.pos),
                         jnp.asarray(wblk), jnp.asarray(woff))
@@ -651,7 +643,6 @@ class ServeEngine:
                 self.stats["paged_inplace_steps"] += 1
             if self.paged_kernel:
                 self.stats["paged_kernel_steps"] += 1
-                self.stats["paged_page_positions"] += page_positions
         finally:
             obslib.end()  # the open serve:step:* phase
             obslib.end()  # serve:decode_step
