@@ -2,13 +2,16 @@
 in place from the pool through the block table.
 
 The pages stay where they are in HBM.  Grid step ``b`` copies into VMEM
-only the pages that hold row ``b``'s cached positions (``< lengths[b]``),
-after starting the copies of row ``b + 1``, so the copies stream back to
-back while each row computes.  A page holds ``bs`` positions of all
-``Hk`` KV heads, seen as ``bs * Hk`` rows of ``Dh`` (row ``s * Hk + h``):
-every query head scores against every row in one MXU contraction, and a
-mask keeps its own head at the row's cached positions (``Hk`` times the
-score FLOPs, which decode has to spare), so no page is relaid.
+only the chunks of pages that hold row ``b``'s cached positions (``<
+lengths[b]``), after starting the copies of row ``b + 1``, so the copies
+stream back to back while each row computes.  A chunk whose table entries
+are consecutive pages (the runs ``launch.kv_cache`` hands out) moves in
+one K and one V copy; any other chunk moves page by page.  A page holds
+``bs`` positions of all ``Hk`` KV heads, seen as ``bs * Hk`` rows of
+``Dh`` (row ``s * Hk + h``): every query head scores against every row
+in one MXU contraction, and a mask keeps its own head at the row's
+cached positions (``Hk`` times the score FLOPs, which decode has to
+spare), so no page is relaid.
 
 The result is the unnormalized online-softmax state ``(acc, m, l)``
 (``ref.paged_attention``'s contract), so the caller can merge keys the
@@ -31,7 +34,10 @@ _LANES = 128
 _MAX_CHUNK = 16
 
 
-def _chunk(width: int) -> int:
+def pages_per_copy(width: int) -> int:
+    """Pages of one chunk of a ``width``-page table: the longest run of
+    consecutive pages the kernel moves in one copy, and the run length
+    the allocator hands a slot (16 at the tables of every cell)."""
     c = _MAX_CHUNK
     while width % c:
         c //= 2
@@ -69,25 +75,45 @@ def _make_kernel(*, bs: int, hk: int, group: int, chunk: int, scale: float):
                acc_ref, m_ref, l_ref, k_buf, v_buf, sems):
         b = pl.program_id(0)
         dh = q_ref.shape[-1]
+        pairs = ((k_hbm, k_buf), (v_hbm, v_buf))
 
         def n_chunks(r):
             return (lens_ref[r] + chunk * bs - 1) // (chunk * bs)
 
-        def copies(r, slot, c):
-            out = []
-            for j in range(chunk):
-                w = c * chunk + j
-                page = pages_ref[r, w]
-                out.append(pltpu.make_async_copy(
-                    k_hbm.at[page], k_buf.at[slot, w], sems.at[0, slot, c]))
-                out.append(pltpu.make_async_copy(
-                    v_hbm.at[page], v_buf.at[slot, w], sems.at[1, slot, c]))
-            return out
+        def copies(r, slot, c, act):
+            """``act`` (start or wait) on each copy of row ``r``'s chunk
+            ``c``: one K and one V copy of the whole chunk where its table
+            entries are consecutive pages, else one of each per page.
+            Start and wait read the same entries, so take the same
+            branch."""
+            w0 = c * chunk
+            first = pages_ref[r, w0]
+
+            def run():
+                for i, (hbm, buf) in enumerate(pairs):
+                    act(pltpu.make_async_copy(
+                        hbm.at[pl.ds(first, chunk)],
+                        buf.at[slot, pl.ds(w0, chunk)], sems.at[i, slot, c]))
+
+            def pages():
+                for j in range(chunk):
+                    page = pages_ref[r, w0 + j]
+                    for i, (hbm, buf) in enumerate(pairs):
+                        act(pltpu.make_async_copy(
+                            hbm.at[page], buf.at[slot, w0 + j],
+                            sems.at[i, slot, c]))
+
+            if chunk == 1:
+                run()
+                return
+            whole = functools.reduce(jnp.logical_and, [
+                pages_ref[r, w0 + j] == first + j for j in range(1, chunk)])
+            pl.when(whole)(run)
+            pl.when(jnp.logical_not(whole))(pages)
 
         def start(r, slot):
             def body(c, carry):
-                for d in copies(r, slot, c):
-                    d.start()
+                copies(r, slot, c, lambda d: d.start())
                 return carry
             jax.lax.fori_loop(0, n_chunks(r), body, 0)
 
@@ -110,8 +136,7 @@ def _make_kernel(*, bs: int, hk: int, group: int, chunk: int, scale: float):
 
         def body(c, carry):
             m, l, acc = carry
-            for d in copies(b, slot, c):
-                d.wait()
+            copies(b, slot, c, lambda d: d.wait())
             k = k_buf[slot, pl.ds(c * chunk, chunk)].reshape(-1, dh)
             v = v_buf[slot, pl.ds(c * chunk, chunk)].reshape(-1, dh)
             s = jax.lax.dot_general(
@@ -156,7 +181,7 @@ def paged_attention(
     b, hq, dh = q.shape
     n_pages, bs, hk, _ = k_pages.shape
     width = blocks.shape[1]
-    chunk = _chunk(width)
+    chunk = pages_per_copy(width)
     rows = bs * hk
     kernel = _make_kernel(bs=bs, hk=hk, group=hq // hk, chunk=chunk,
                           scale=dh ** -0.5)

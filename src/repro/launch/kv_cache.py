@@ -16,6 +16,15 @@ per slot instead of one dense ``(slots, max_seq, ...)`` tensor per layer:
   to the free list on eviction — the continuous-batching scheduler's
   admission control can therefore run the pool smaller than
   ``slots * blocks_per_slot`` and queue requests under memory pressure.
+* A pool of the default size hands out pages in runs: a slot entering
+  table entry ``j`` with ``j % run == 0`` takes ``run`` physically
+  consecutive pages at once (``run =
+  kernels.paged_attention.pages_per_copy(blocks_per_slot)``, the kernel's
+  chunk), and its growth inside the run allocates nothing, so the kernel
+  copies the chunk in one piece.  Such a pool holds every slot at
+  ``max_seq`` in whole runs, so runs cost it no admission.  A smaller
+  pool (admission control) hands out single pages, so no slot holds
+  pages it does not use.
 * A decode step (``launch.serve.make_paged_step``) reads the pool in
   place: ``models.model.decode_step_paged`` hands each layer its pages
   through the block table inside the layer scan, and
@@ -42,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention import pages_per_copy
 from repro.models import model as M
 
 
@@ -103,8 +113,10 @@ class PagedKVCache:
 
     ``num_blocks`` bounds the physical pool (default: enough for every
     slot at ``max_seq``, i.e. no admission pressure); one extra scratch
-    page is always added on top.  All table/free-list bookkeeping is
-    host-side numpy — only the pool itself lives on device.
+    page is always added on top.  ``run`` is the number of consecutive
+    pages handed out at once: the kernel's chunk where the pool holds
+    every slot at ``max_seq``, else 1.  All table/free-list bookkeeping
+    is host-side numpy — only the pool itself lives on device.
     """
 
     def __init__(self, cfg, *, slots: int, max_seq: int, block_size: int,
@@ -132,24 +144,34 @@ class PagedKVCache:
         self.pool = M.init_cache(cfg, self.num_blocks + 1, block_size)
         self.tables = np.zeros((slots, self.blocks_per_slot), np.int32)
         self.n_alloc = np.zeros(slots, np.int32)
-        self._free = list(range(self.num_blocks, 0, -1))
+        full = self.num_blocks >= slots * self.blocks_per_slot
+        self.run = pages_per_copy(self.blocks_per_slot) if full else 1
+        # the free list holds the first page of each free run
+        self._free = list(range(
+            1 + (self.num_blocks // self.run - 1) * self.run, 0, -self.run))
 
     @property
     def free_blocks(self) -> int:
-        return len(self._free)
+        return self.run * len(self._free)
 
     def blocks_for(self, n_tokens: int) -> int:
         return -(-n_tokens // self.block_size)
 
+    def _covered(self, n_tokens: int) -> int:
+        """Table entries a slot holds once it covers ``n_tokens``: whole
+        runs."""
+        return -(-self.blocks_for(n_tokens) // self.run) * self.run
+
     def can_allocate(self, slot: int, n_tokens: int) -> bool:
-        need = self.blocks_for(n_tokens) - int(self.n_alloc[slot])
-        return need <= len(self._free)
+        need = self._covered(n_tokens) - int(self.n_alloc[slot])
+        return need <= self.free_blocks
 
     def allocate(self, slot: int, n_tokens: int) -> bool:
-        """Grow ``slot`` to cover ``n_tokens`` positions.  Returns False
-        (allocating nothing) when the free list cannot cover the growth —
-        the scheduler's admission-control signal."""
-        need = self.blocks_for(n_tokens)
+        """Grow ``slot`` to cover ``n_tokens`` positions, a run at a
+        time.  Returns False (allocating nothing) when the free list
+        cannot cover the growth — the scheduler's admission-control
+        signal."""
+        need = self._covered(n_tokens)
         have = int(self.n_alloc[slot])
         if need <= have:
             return True
@@ -157,16 +179,18 @@ class PagedKVCache:
             raise ValueError(
                 f"slot {slot}: {n_tokens} tokens exceed max_seq "
                 f"{self.max_seq}")
-        if need - have > len(self._free):
+        if need - have > self.free_blocks:
             return False
-        for j in range(have, need):
-            self.tables[slot, j] = self._free.pop()
+        for j in range(have, need, self.run):
+            first = self._free.pop()
+            self.tables[slot, j:j + self.run] = range(first,
+                                                      first + self.run)
         self.n_alloc[slot] = need
         return True
 
     def release(self, slot: int) -> None:
-        """Return the slot's pages to the free list (eviction)."""
-        for j in range(int(self.n_alloc[slot])):
+        """Return the slot's runs to the free list (eviction)."""
+        for j in range(0, int(self.n_alloc[slot]), self.run):
             self._free.append(int(self.tables[slot, j]))
         self.tables[slot, :] = 0
         self.n_alloc[slot] = 0

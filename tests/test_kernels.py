@@ -210,6 +210,43 @@ def test_paged_attention_mqa_at_the_engines_page_length(dtype):
     assert float(l[2].max()) == 0.0 and float(abs(acc[2]).max()) == 0.0
 
 
+@pytest.mark.parametrize("layout", ["runs", "one_chunk_broken"])
+@pytest.mark.parametrize("hq,hk,bs", [(8, 4, 8), (48, 1, 16)])
+def test_paged_attention_over_tables_in_runs(hq, hk, bs, layout):
+    """Tables as the allocator lays them out: each chunk of the table one
+    run of consecutive pages (copied whole), the runs in no order through
+    the pool; and the same with one page in the middle of a live chunk
+    swapped for a page elsewhere, which that chunk must copy page by
+    page.  Rows end mid-run, on a run's edge and at the full table, with
+    a dead row."""
+    dtype, width, dh = jnp.bfloat16, 32, 128
+    chunk = _pa.pages_per_copy(width)
+    assert chunk == 16
+    lengths = jnp.array([5 * bs + 3, 0, chunk * bs, (chunk + 7) * bs + 2,
+                         width * bs], jnp.int32)
+    b = lengths.shape[0]
+    runs = b * width // chunk
+    n_pages = 1 + runs * chunk + 1      # a scratch page first, a spare last
+    first = 1 + chunk * np.random.default_rng(0).permutation(runs)
+    blocks = (first.reshape(b, width // chunk, 1)
+              + np.arange(chunk)).reshape(b, width)
+    blocks[1] = 0                       # the dead row points at scratch
+    if layout == "one_chunk_broken":
+        blocks[3, chunk + 4] = n_pages - 1
+    blocks = jnp.asarray(blocks, jnp.int32)
+    q = rnd(0, (b, hq, dh), dtype)
+    k = rnd(1, (n_pages, bs, hk, dh), dtype)
+    v = rnd(2, (n_pages, bs, hk, dh), dtype)
+    acc, m, l = paged_attention(q, k, v, blocks, lengths, interpret=True)
+    racc, rm, rl = ref.paged_attention(q, k, v, blocks, lengths)
+    t = tol(dtype)
+    live = lengths > 0
+    np.testing.assert_allclose(m[live], rm[live], **t)
+    np.testing.assert_allclose((acc / l[..., None])[live],
+                               (racc / rl[..., None])[live], **t)
+    assert float(l[1].max()) == 0.0 and float(abs(acc[1]).max()) == 0.0
+
+
 # ---------------------------------------------------------------------------
 # recurrent kernels
 # ---------------------------------------------------------------------------

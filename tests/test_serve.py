@@ -549,3 +549,105 @@ def test_paged_engine_counts_kernel_steps_and_page_positions(
         "samples"]}
     assert got["paged_kernel_steps"] == got["decode_steps"] == 3
     assert all(len(out) == 4 for out in done.values())
+
+
+# ---------------------------------------------------------------------------
+# the allocator hands out pages in runs the kernel copies in one piece
+# ---------------------------------------------------------------------------
+
+def _is_run(pages) -> bool:
+    return bool((np.diff(pages) == 1).all())
+
+
+def test_slot_entering_a_run_takes_a_whole_aligned_run(setup):
+    """In the default pool a slot's first page and the first page past
+    each run take a whole run of consecutive pages into the run's table
+    entries; growth inside the run allocates nothing; release returns
+    every page."""
+    cfg, _ = setup
+    kv = KV.PagedKVCache(cfg, slots=2, max_seq=256, block_size=8)
+    run = kv.run
+    assert run == PA.pages_per_copy(kv.blocks_per_slot) == 16
+    start = kv.free_blocks
+    assert start == kv.num_blocks == 2 * 32
+
+    assert kv.allocate(0, 3)                     # one position: a run
+    assert kv.n_alloc[0] == run and _is_run(kv.tables[0, :run])
+    assert (kv.tables[0, run:] == 0).all()
+    assert kv.free_blocks == start - run
+    before = kv.tables.copy()
+    for n in (8, 9, 100, run * 8):               # inside the first run
+        assert kv.can_allocate(0, n) and kv.allocate(0, n)
+    assert (kv.tables == before).all() and kv.free_blocks == start - run
+
+    assert kv.allocate(1, 50)
+    assert kv.allocate(0, run * 8 + 1)           # entry 16: the next run
+    assert _is_run(kv.tables[0, run:2 * run])
+    assert _is_run(kv.tables[1, :run])
+    assert kv.free_blocks == start - 3 * run
+    # no page is handed out twice
+    held = np.concatenate([kv.tables[0, :2 * run], kv.tables[1, :run]])
+    assert len(set(held.tolist())) == 3 * run and 0 not in held
+
+    kv.release(0)
+    kv.release(1)
+    assert kv.free_blocks == start
+    assert (kv.tables == 0).all() and (kv.n_alloc == 0).all()
+    # the runs came back whole: the next slot gets one again
+    assert kv.allocate(1, 1) and _is_run(kv.tables[1, :run])
+
+
+@pytest.mark.parametrize("extra", [0, 1, 16])
+def test_pool_of_every_slot_at_max_seq_is_all_runs(setup, extra):
+    """A pool at or above the default size fills every slot to max_seq
+    in runs, with no page left over that a slot could need."""
+    cfg, _ = setup
+    kv = KV.PagedKVCache(cfg, slots=3, max_seq=256, block_size=8,
+                         num_blocks=3 * 32 + extra)
+    assert kv.run == 16
+    for slot in range(3):
+        for n in range(1, 257, 8):
+            assert kv.allocate(slot, n)
+        assert kv.n_alloc[slot] == 32
+        assert all(_is_run(kv.tables[slot, r:r + 16]) for r in (0, 16))
+    assert len(set(kv.tables.ravel().tolist())) == 3 * 32
+    assert kv.free_blocks == 16 * (extra // 16)
+
+
+def test_pressured_pool_hands_out_single_pages(setup):
+    """A pool smaller than every slot at max_seq hands out one page at a
+    time, as many as each slot covers: two slots hold 2 and 8 pages of a
+    32-page pool, and the first still grows to 17 pages past position
+    128.  Pages in runs would have taken the whole pool for the two."""
+    cfg, _ = setup
+    kv = KV.PagedKVCache(cfg, slots=2, max_seq=256, block_size=8,
+                         num_blocks=32)
+    assert kv.run == 1
+    assert kv.allocate(0, 16) and kv.n_alloc[0] == 2
+    assert kv.allocate(1, 64) and kv.n_alloc[1] == 8
+    assert kv.free_blocks == 22
+    for n in range(17, 130):
+        assert kv.allocate(0, n)
+    assert kv.n_alloc[0] == 17 and kv.free_blocks == 7
+    assert not kv.can_allocate(1, 8 * 24)        # 16 more: refused
+    assert not kv.allocate(1, 8 * 24) and kv.n_alloc[1] == 8
+    kv.release(0)
+    kv.release(1)
+    assert kv.free_blocks == 32 and (kv.tables == 0).all()
+
+
+def test_pressured_pool_admits_every_slot_it_can_hold(setup):
+    """Under admission control each slot holds only the pages it covers:
+    three 2-page prompts share a 6-page pool at once, and a fourth slot
+    waits for a page to come free."""
+    cfg, params = setup
+    rng = np.random.default_rng(16)
+    reqs = [Request(i, rng.integers(2, cfg.vocab_size, size=10)
+                    .astype(np.int32), 3) for i in range(4)]
+    eng = ServeEngine(cfg, params, batch_slots=4, max_seq=32,
+                      block_size=8, kv_blocks=6, eos_id=-1)
+    assert eng.kv.run == 1
+    for slot, req in enumerate(reqs[:3]):
+        assert eng._admit(req, slot, {})
+    assert eng.kv.free_blocks == 0
+    assert not eng._admit(reqs[3], 3, {})
