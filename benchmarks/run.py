@@ -24,10 +24,9 @@ def _write_status(results: list[dict]) -> None:
 
 def main() -> None:
     from . import (bench_attention, bench_autotune, bench_block,
-                   bench_calibrate, bench_mesh, bench_obs,
-                   bench_paper_mlp, bench_roofline, bench_schedule,
-                   bench_serve, bench_solver, bench_targets,
-                   bench_tpu_mlp)
+                   bench_calibrate, bench_mesh, bench_paper_mlp,
+                   bench_roofline, bench_schedule, bench_solver,
+                   bench_targets, bench_tpu_mlp)
 
     sections = [
         ("targets: per-level traffic across memory hierarchies + gate",
@@ -44,14 +43,10 @@ def main() -> None:
         ("ftl-solver: branch-and-bound performance", bench_solver.main),
         ("block-exec: layer-per-layer vs BlockPlan-driven whole block",
          bench_block.main),
-        ("serve: continuous batching tokens/s + latency, open-loop + gate",
-         bench_serve.main),
         ("mesh: collective-aware 1->N scaling + multi-port overlap + gate",
          bench_mesh.main),
         ("calibrate: fitted Target constants + modeled-vs-measured "
          "drift gate", bench_calibrate.main),
-        ("obs: telemetry overhead + online drift monitor + gates",
-         bench_obs.main),
         ("roofline: dry-run artifacts (per arch x shape x mesh)",
          bench_roofline.main),
     ]

@@ -164,8 +164,7 @@ def _sorted(ran: dict) -> dict:
 def _expected_prefill(eng) -> dict[str, set[str]]:
     """Per stage kind, the executors every bucket's prefill plan binds."""
     want: dict[str, set[str]] = {}
-    for b in eng.buckets:
-        _, plan = eng.plans.get(b, "prefill")
+    for b, plan in eng.prefill_plans.items():
         for kind, name in executor_block.resolved_executors(
                 plan, m=b).items():
             want.setdefault(kind, set()).add(name)
@@ -215,8 +214,7 @@ def serve_mode(cfg, params, mode: str, *, slots: int, max_seq: int,
                     f"{mode}: prefill {kind} resolved {want[kind]}, "
                     f"expected {name}")
         b = eng.buckets[-1]
-        _, plan = eng.plans.get(b, "prefill")
-        hlo = eng.prefill_fn(b, plan).lower(
+        hlo = eng.prefill_fn(b, eng.prefill_plans[b]).lower(
             params, {"tokens": jnp.zeros((1, b), jnp.int32)},
             jnp.int32(b - 1)).compile().as_text()
         if "tpu_custom_call" not in hlo:
@@ -241,10 +239,10 @@ def served_logits(eng: ServeEngine, prompt: np.ndarray, n_new: int
     prefill and paged decode steps; returns the float32 logits of every
     generated position (n_new, vocab) and the generated tokens."""
     plen = len(prompt)
-    bucket, plan = eng.plans.get(plen, "prefill")
+    bucket = M.bucket_m(plen, eng.buckets)
     padded = np.zeros(bucket, np.int32)
     padded[:plen] = prompt
-    logits, cache1 = eng.prefill_fn(bucket, plan)(
+    logits, cache1 = eng.prefill_fn(bucket, eng.prefill_plans[bucket])(
         eng.params, {"tokens": jnp.asarray(padded)[None]},
         jnp.int32(plen - 1))
     eng.kv.write_prefill(0, cache1, bucket)

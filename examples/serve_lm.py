@@ -4,8 +4,8 @@ Admits a queue of prompt requests into fixed decode slots, prefills each
 at its bucketed length (paged KV cache when the config supports it),
 then decodes all active slots together — each at its own position.
 Prefill and decode run on *separate* FTL plans (the memory-bound m=1
-decode DP generally picks different cuts), both AOT-warmed so steady
-state never replans.
+decode DP generally picks different cuts), all planned once when the
+engine is built.
 
 Run:  PYTHONPATH=src python examples/serve_lm.py
 
@@ -17,8 +17,7 @@ Extra flags pass straight through to ``repro.launch.serve``:
                            simulated schedule (load in Perfetto or
                            chrome://tracing); with --obs it becomes the
                            merged live+modeled timeline, written post-run
-  --obs                    runtime telemetry: lifecycle spans, queue/KV
-                           gauges, the online drift monitor
+  --obs                    record the engine's lifecycle spans
   --obs-trace live.json    merged live+modeled Perfetto timeline
                            (implies --obs; must differ from --trace —
                            the same path is rejected, not overwritten)

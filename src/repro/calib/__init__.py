@@ -23,14 +23,13 @@ from .fit import (CalibrationResult, Residual, calibrate, drift_gate,
 from .measure import (COMPUTE, TRANSFER, Measurement, SegmentFeatures,
                       features_from_chain, measure_block,
                       measure_dma_proxy, measure_elementwise,
-                      measure_gemms, measurement_from_chain,
-                      microbench_sweep, modeled_measurement_s,
+                      measure_gemms, microbench_sweep, modeled_measurement_s,
                       wallclock_s)
 
 __all__ = [
     "COMPUTE", "TRANSFER", "Measurement", "SegmentFeatures",
     "modeled_measurement_s", "features_from_chain",
-    "measurement_from_chain", "wallclock_s",
+    "wallclock_s",
     "measure_gemms", "measure_elementwise", "measure_dma_proxy",
     "microbench_sweep", "measure_block",
     "nnls", "Residual", "CalibrationResult", "calibrate", "drift_gate",

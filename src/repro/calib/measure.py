@@ -117,16 +117,6 @@ def modeled_measurement_s(target: hwlib.Target, m: Measurement) -> float:
 # feature extraction
 # ---------------------------------------------------------------------------
 
-def measurement_from_chain(name: str, chain, measured_s: float, *,
-                           kind: str = "block",
-                           meta: tuple = ()) -> Measurement:
-    """Wrap a live wall-clock observation of a planned chain (or
-    ``BlockPlan``) as a validation :class:`Measurement` — the record
-    the online drift monitor (:mod:`repro.obs.drift`) feeds from."""
-    return Measurement(name=name, kind=kind, measured_s=measured_s,
-                       segments=features_from_chain(chain), meta=meta)
-
-
 def features_from_chain(chain) -> tuple[SegmentFeatures, ...]:
     """Per-segment roofline features of a planned chain (``ChainPlan`` or
     a ``BlockPlan`` via ``.chain``) — what a whole-block wall-clock

@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import weakref
 from typing import Callable, Mapping
 
 import jax
@@ -624,26 +623,12 @@ def mlp_executor(
 # ---------------------------------------------------------------------------
 
 _PLAN_CACHES: dict[str, Callable] = {}
-# Stat-keeping consumers of the caches (ServeEngine plan caches and the
-# engines themselves): anything enrolled here has its ``reset_counters``
-# called by :func:`clear_plan_caches`, so reuse counters can never claim
-# cache hits that a clear just invalidated.  Weak references — a
-# registered engine dies with its last real owner, not with the ledger.
-_COUNTER_RESETS: "weakref.WeakSet" = weakref.WeakSet()
-
-
-def register_counter_reset(obj) -> Callable | object:
-    """Enroll an object exposing ``reset_counters()`` to be reset
-    whenever :func:`clear_plan_caches` drops the underlying caches.
-    Held weakly; returns ``obj``."""
-    _COUNTER_RESETS.add(obj)
-    return obj
 
 
 def register_plan_cache(name: str, fn: Callable) -> Callable:
     """Enroll an ``lru_cache``-wrapped planner in the plan-cache ledger.
 
-    Higher layers (``repro.models.model``, ``repro.tune``) self-register
+    Higher layers (``repro.core.ftl``'s shims, ``repro.tune``) self-register
     at import, so :func:`plan_cache_stats` covers every *imported*
     planner cache without this module depending on them.  Returns ``fn``
     so the call composes as a decorator-style tail."""
@@ -669,15 +654,9 @@ def plan_cache_stats() -> dict[str, dict[str, int]]:
 
 def clear_plan_caches() -> None:
     """Drop every registered planner cache (tests; target registry
-    edits that would otherwise serve stale plans) — and reset the
-    counters of every registered stat keeper (``ServeEngine`` plan
-    caches), so ``plan_report()`` after a clear reports the reuse that
-    actually happened, not hit/replan totals from before the plans were
-    invalidated."""
+    edits that would otherwise serve stale plans)."""
     for fn in _PLAN_CACHES.values():
         fn.cache_clear()
-    for obj in list(_COUNTER_RESETS):
-        obj.reset_counters()
 
 
 for _fn in (_mlp_kernel_footprint_fits, _partial_mlp_footprint_fits,

@@ -12,11 +12,11 @@ A real serving loop on top of the FTL planning stack:
   (vector ``pos`` through ``model.decode_step``): admission prefills at
   the request's bucketed length, decode appends per slot, eviction on
   EOS/max-len refills the slot from the queue.
-* **Plan cache** — serving plans are keyed ``(cfg, bucketed m, dtype,
-  target, phase)``.  Prompts bucket through the
-  :data:`repro.models.model.PREFILL_BUCKETS` ladder (ahead-of-time
-  warmed), so steady state replans exactly zero times; the CI bench
-  gates on that.
+* **Plan table** — the engine plans every rung of its prefill bucket
+  ladder (:data:`repro.models.model.PREFILL_BUCKETS`, clipped to
+  ``max_seq``) and the decode shape once, at construction; admission
+  looks its bucket's plan up in that table and decode never looks one
+  up at all.
 * **Split prefill/decode plans** — decode plans at ``m=1`` run through
   the same partition DP as prefill; memory-bound, they generally pick
   different cuts (pinned on ``rv32_npu``), and their bindings never
@@ -51,11 +51,6 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.train import steps as S
 
-# how often an obs-enabled engine samples a decode step into the drift
-# monitor (report-only rows; whole-block rows come from
-# execute_block_plan and are the ones benches gate on)
-_DRIFT_SAMPLE_EVERY = 16
-
 
 @dataclasses.dataclass
 class Request:
@@ -74,72 +69,6 @@ class Request:
     def latency_s(self) -> float:
         """Arrival → completion, including queueing for a slot."""
         return self.t_done - self.t_arrival
-
-
-class PlanCache:
-    """Serving plan cache keyed ``(cfg, bucketed m, dtype, target, phase)``.
-
-    A thin counting wrapper over :func:`repro.models.model.serve_plan`:
-    ``warmup`` pre-plans the whole prefill bucket ladder plus the decode
-    plan, after which every lookup must hit — ``misses_after_warmup`` is
-    the CI gate's "zero replans during steady-state decode" counter.
-    """
-
-    def __init__(self, cfg, *, dtype: str, target: hw.Target,
-                 buckets: tuple[int, ...]):
-        self.cfg = cfg
-        self.dtype = dtype
-        self.target = target
-        self.buckets = tuple(buckets)
-        self._plans: dict[tuple, object] = {}
-        self.hits = 0
-        self.misses = 0
-        self.warmed = False
-        self.misses_after_warmup: list[tuple[str, int]] = []
-        # a global cache clear must not leave this wrapper claiming
-        # hits/warmth for plans the clear just dropped
-        ftl_registry.register_counter_reset(self)
-
-    def get(self, m: int, phase: str):
-        """(bucketed m, BlockPlan-or-None) for one lookup."""
-        mb = 1 if phase == "decode" else M.bucket_m(m, self.buckets)
-        key = (self.cfg, mb, self.dtype, self.target, phase)
-        if key in self._plans:
-            self.hits += 1
-            return mb, self._plans[key]
-        self.misses += 1
-        if self.warmed:
-            self.misses_after_warmup.append((phase, mb))
-        _, plan = M.serve_plan(self.cfg, m=mb, dtype=self.dtype,
-                               target=self.target, phase=phase,
-                               buckets=self.buckets)
-        self._plans[key] = plan
-        return mb, plan
-
-    def warmup(self) -> None:
-        for b in self.buckets:
-            self.get(b, "prefill")
-        self.get(1, "decode")
-        self.warmed = True
-
-    def counters(self) -> dict:
-        return {
-            "plans": len(self._plans),
-            "hits": self.hits,
-            "misses": self.misses,
-            "misses_after_warmup": len(self.misses_after_warmup),
-        }
-
-    def reset_counters(self) -> None:
-        """Back to the just-constructed state — called by
-        ``registry.clear_plan_caches``.  The held plans are dropped too
-        (they were built by the caches the clear invalidated), so the
-        next lookup genuinely replans and the counters say so."""
-        self._plans.clear()
-        self.hits = 0
-        self.misses = 0
-        self.warmed = False
-        self.misses_after_warmup.clear()
 
 
 def _default_buckets(max_seq: int, block_size: int) -> tuple[int, ...]:
@@ -173,18 +102,19 @@ class ServeEngine:
     dense per-slot cache, ``kv_blocks`` shrinks the physical pool below
     ``slots * max_seq / block_size`` to exercise admission control.
 
-    Besides the step counts, ``stats`` counts the decode steps whose
-    attention ran the Pallas paged kernel (``paged_kernel_steps``)."""
+    Construction plans every rung of the bucket ladder
+    (``prefill_plans``, bucket -> plan) and the decode shape
+    (``decode_plan``); a plan is None where the model has nothing to
+    plan.  Besides the step counts, ``stats`` counts the decode steps
+    whose attention ran the Pallas paged kernel
+    (``paged_kernel_steps``)."""
 
     def __init__(self, cfg, params, *, batch_slots: int, max_seq: int,
                  eos_id: int = 1, target: hw.Target | None = None,
                  block_size: int | None = None,
                  paged: bool | None = None,
                  kv_blocks: int | None = None,
-                 buckets: tuple[int, ...] | None = None,
-                 obs: bool = False,
-                 drift_target: hw.Target | None = None,
-                 drift_band: tuple[float, float] | None = None):
+                 buckets: tuple[int, ...] | None = None):
         self.cfg = cfg
         self.params = params
         self.slots = batch_slots
@@ -229,20 +159,27 @@ class ServeEngine:
         self.paged_kernel = self.paged and ops.paged_kernel(
             block_size, cfg.n_kv_heads, cfg.dtype)
 
-        # AOT warmup of the bucket ladder + the decode plan: after this,
-        # steady state never plans again (the bench gate).
-        self.plans = PlanCache(cfg, dtype=cfg.dtype, target=self.target,
-                               buckets=self.buckets)
-        self.plans.warmup()
-        _, self.decode_plan = self.plans.get(1, "decode")
-        _, self.block_plan = self.plans.get(self.buckets[-1], "prefill")
-        self.decode_fn = self._build_decode(self.decode_plan)
-        self._decode_fn_plan = self.decode_plan
+        # the plan table: every rung of the ladder and the decode shape,
+        # planned once here; serving never plans again
+        def plan(m, phase):
+            return M.plan_or_none(cfg, m=m, dtype=cfg.dtype,
+                                  target=self.target, phase=phase)
+
+        self.prefill_plans = {b: plan(b, "prefill") for b in self.buckets}
+        self.decode_plan = plan(1, "decode")
+        self.block_plan = self.prefill_plans[self.buckets[-1]]
+        if self.paged:
+            self.decode_fn = make_paged_step(cfg, self.decode_plan)
+        else:
+            self.decode_fn = jax.jit(
+                S.make_decode_step(cfg, None, plan=self.decode_plan))
         self._prefill_fns: dict[int, Any] = {}
 
         self.stats = {
             "prefills": 0, "decode_steps": 0, "tokens": 0,
-            "replans": 0, "paged_inplace_steps": 0,
+            # always 0 (the plans are fixed at construction); kept
+            # because bench/timing.py reads the key
+            "replans": 0,
             "paged_kernel_steps": 0,
             "bucket_admissions": {},
             "ftl_schedule": (self.block_plan.schedule
@@ -250,37 +187,10 @@ class ServeEngine:
             "ftl_target": self.target.name,
             "block_exec": "n/a",
         }
-        ftl_registry.register_counter_reset(self)
-
-        # telemetry (repro.obs): span recording + per-step gauges + the
-        # online drift monitor, all opt-in — a bare engine pays nothing
-        # but its counters, read only when the registry is collected.
-        self.obs = bool(obs)
-        self.drift = None
         self._register_collector()
-        if self.obs:
-            obslib.enable()
-            self.drift = obslib.DriftMonitor(
-                target=drift_target if drift_target is not None
-                else self.target,
-                **({"band": drift_band} if drift_band else {}))
-            self._g_active = obslib.gauge(
-                "serve_active_slots", "slots currently decoding")
-            self._g_queue = obslib.gauge(
-                "serve_queue_depth", "requests waiting for a slot")
-            self._g_kv_free = obslib.gauge(
-                "serve_kv_free_blocks", "paged-KV free physical blocks")
-            self._g_kv_occ = obslib.gauge(
-                "serve_kv_page_occupancy",
-                "fraction of the paged-KV pool in use")
-            self._c_evict = obslib.counter(
-                "serve_evictions_total", "slots freed (EOS/max-len)")
-            self._h_step = obslib.histogram(
-                "serve_decode_step_seconds", "wall-clock per decode step")
 
     def _register_collector(self) -> None:
-        """Re-express ``stats`` (every engine) and ``plan_report()``
-        (obs-enabled engines) on the metrics registry at collect time.
+        """Re-express ``stats`` on the metrics registry at collect time.
         Weakly bound: a dead engine's collector is a no-op, never a
         leak."""
         ref = weakref.ref(self)
@@ -292,45 +202,12 @@ class ServeEngine:
             g_stat = reg.gauge("serve_stats",
                                "ServeEngine.stats re-expressed", ("stat",))
             for k in ("prefills", "decode_steps", "tokens", "replans",
-                      "paged_inplace_steps", "paged_kernel_steps"):
+                      "paged_kernel_steps"):
                 g_stat.labels(stat=k).set(eng.stats[k])
-            if not eng.obs:
-                return
-            g_pc = reg.gauge("serve_plan_cache",
-                             "serving PlanCache counters", ("field",))
-            for k, v in eng.plans.counters().items():
-                g_pc.labels(field=k).set(v)
-            rep = eng.plan_report()
-            g_plan = reg.gauge(
-                "serve_plan_segments",
-                "planned segments per serving regime (0 = no plan)",
-                ("phase", "schedule"))
-            for phase in ("prefill", "decode"):
-                e = rep[phase]
-                if e is not None:
-                    g_plan.labels(phase=phase, schedule=e["schedule"]) \
-                        .set(len(e["cuts"]) + 1)
-            reg.gauge("serve_decode_differs_from_prefill",
-                      "1 when the decode DP picked different cuts") \
-                .set(float(rep["decode_differs_from_prefill"]))
 
         obslib.register_collector(_collect)
 
-    def reset_counters(self) -> None:
-        """Called by ``registry.clear_plan_caches``: the decode-replan
-        counter tracks misses of the (just-reset) plan cache, so it must
-        restart with it or ``plan_report`` would blame post-clear replans
-        on steady-state serving."""
-        self.stats["replans"] = 0
-
     # ------------------------------------------------------------------
-    # plan-aware step builders
-    # ------------------------------------------------------------------
-    def _build_decode(self, plan):
-        if self.paged:
-            return make_paged_step(self.cfg, plan)
-        return jax.jit(S.make_decode_step(self.cfg, None, plan=plan))
-
     def prefill_fn(self, bucket: int, plan):
         """The engine's jitted prefill step for ``bucket`` (built once);
         ``decode_fn`` is its jitted decode step."""
@@ -366,8 +243,7 @@ class ServeEngine:
             "decode": dec,
             "decode_differs_from_prefill": bool(
                 pre and dec and pre["cuts"] != dec["cuts"]),
-            # every memoized planner the serving path leans on — shows
-            # the plans above came out of cache, not replanning
+            # hits, misses and sizes of every memoized planner
             "plan_caches": ftl_registry.plan_cache_stats(),
         }
 
@@ -377,8 +253,7 @@ class ServeEngine:
         XLA compiles.  Engine state is untouched but for the paged
         pool's scratch page, which the warm-up decode step writes."""
         extras = extras or {}
-        for b in self.buckets:
-            _, plan = self.plans.get(b, "prefill")
+        for b, plan in self.prefill_plans.items():
             fn = self.prefill_fn(b, plan)
             batch = {"tokens": jnp.zeros((1, b), jnp.int32), **extras}
             fn(self.params, batch, jnp.int32(b - 1))[0].block_until_ready()
@@ -440,12 +315,6 @@ class ServeEngine:
             "finite": bool(jnp.isfinite(y).all()),
         }
         self.stats["block_exec"] = entry
-        if self.drift is not None:
-            # the gated drift feed: a whole planned block, wall-clocked
-            # at the serving shape — the same regime bench_calibrate's
-            # block rows measure
-            entry["drift_ratio"] = self.drift.observe_chain(
-                self.block_plan, dt, name="block_exec", kind="block")
         return entry
 
     def _first_block_params(self):
@@ -495,7 +364,8 @@ class ServeEngine:
         t_admit = time.perf_counter()
         obslib.begin("serve:admit", "serve")
         try:
-            bucket, plan = self.plans.get(plen, "prefill")
+            bucket = M.bucket_m(plen, self.buckets)
+            plan = self.prefill_plans[bucket]
             req.bucket = bucket
             if self.paged and not self.kv.allocate(slot, bucket):
                 return False
@@ -561,32 +431,19 @@ class ServeEngine:
             self.pos[slot] = 0
             if self.paged:
                 self.kv.release(slot)
-        if self.obs:
-            self._c_evict.inc()
 
     def step(self):
         """One batched decode step for all active slots (each at its own
         position).
 
         With recording on, four spans tile ``serve:decode_step``:
-        ``prepare`` (plan lookup, slot scan, page allocation, host
-        arrays), ``launch`` (the jitted step's dispatch), ``sync`` (the
-        sampling dispatch and the tokens' readback, which waits for the
-        device) and ``commit`` (token bookkeeping)."""
-        t_step = time.perf_counter() if self.obs else 0.0
+        ``prepare`` (slot scan, page allocation, host arrays), ``launch``
+        (the jitted step's dispatch), ``sync`` (the sampling dispatch and
+        the tokens' readback, which waits for the device) and ``commit``
+        (token bookkeeping)."""
         obslib.begin("serve:decode_step", "serve")
         obslib.begin("serve:step:prepare", "serve")
         try:
-            # steady-state plan lookup: after warmup this always hits; a
-            # miss (or a changed plan object) would force a re-jit —
-            # counted as a replan, and gated to zero in bench_serve
-            _, plan = self.plans.get(1, "decode")
-            if plan is not self._decode_fn_plan:
-                self.decode_fn = self._build_decode(plan)
-                self._decode_fn_plan = plan
-                self.decode_plan = plan
-                self.stats["replans"] += 1
-
             tok = np.zeros((self.slots, 1), np.int32)
             live = np.zeros(self.slots, bool)
             for i, r in enumerate(self.active):
@@ -639,34 +496,11 @@ class ServeEngine:
                     r.done = True
                     r.t_done = now
             self.stats["decode_steps"] += 1
-            if self.paged:
-                self.stats["paged_inplace_steps"] += 1
             if self.paged_kernel:
                 self.stats["paged_kernel_steps"] += 1
         finally:
             obslib.end()  # the open serve:step:* phase
             obslib.end()  # serve:decode_step
-        if self.obs:
-            self._observe_step(time.perf_counter() - t_step)
-
-    def _observe_step(self, dt: float) -> None:
-        """Per-step gauges + a sampled drift row (obs-enabled engines)."""
-        self._h_step.observe(dt)
-        self._g_active.set(sum(1 for r in self.active
-                               if r is not None and not r.done))
-        if self.paged:
-            free = self.kv.free_blocks
-            self._g_kv_free.set(free)
-            self._g_kv_occ.set(1.0 - free / max(self.kv.num_blocks, 1))
-        if (self.drift is not None and self.decode_plan is not None
-                and self.stats["decode_steps"] % _DRIFT_SAMPLE_EVERY == 0):
-            # report-only row: a decode step runs the per-block plan
-            # n_layers times (plus head/dispatch the model never charges
-            # the block plan for), so scale the modeled side to match.
-            # Whole-block rows from execute_block_plan are the gated ones.
-            self.drift.observe_chain(
-                self.decode_plan, dt, name="decode_step", kind="decode",
-                scale=max(self.cfg.n_layers, 1))
 
     def run(self, requests: list[Request], extras: dict[str, Any],
             arrivals: list[float] | None = None):
@@ -694,8 +528,6 @@ class ServeEngine:
             obslib.begin("serve:schedule", "serve")
             try:
                 now = time.perf_counter()
-                if self.obs:
-                    self._g_queue.set(len(queue))
                 admitted_any = False
                 for i in range(self.slots):
                     r = self.active[i]
@@ -778,8 +610,7 @@ def main() -> None:
                     "plan's simulated schedule to this path (with --obs: "
                     "the merged live+modeled timeline, written post-run)")
     ap.add_argument("--obs", action="store_true",
-                    help="enable runtime telemetry (spans, gauges, the "
-                    "online drift monitor)")
+                    help="record runtime spans (obs.enable)")
     ap.add_argument("--obs-trace", default=None,
                     help="write the merged live+modeled Perfetto "
                     "timeline to this path after the run (implies --obs)")
@@ -819,11 +650,12 @@ def main() -> None:
                     args.max_new)
             for i in range(args.requests)]
     target = hw.get_target(args.target) if args.target else None
+    if args.obs:
+        obslib.enable()
     eng = ServeEngine(cfg, params, batch_slots=args.slots,
                       max_seq=args.max_seq, target=target,
                       block_size=args.block_size,
-                      paged=False if args.dense_kv else None,
-                      obs=args.obs)
+                      paged=False if args.dense_kv else None)
     report = eng.plan_report()
     print(f"FTL serving plans on {report['target']} "
           f"(buckets {report['buckets']}, "
@@ -873,20 +705,10 @@ def main() -> None:
           f"{eng.stats['decode_steps']} decode steps, "
           f"{eng.stats['prefills']} prefills, "
           f"p50 {1e3*p50:.0f} ms / p99 {1e3*p99:.0f} ms")
-    pc = eng.plans.counters()
-    print(f"plan cache: {pc['plans']} plans, {pc['hits']} hits, "
-          f"{pc['misses']} misses ({pc['misses_after_warmup']} after "
-          f"warmup), {eng.stats['replans']} decode replans")
     for r in done[:3]:
         print(f"  req {r.rid}: {len(r.out)} tokens: {r.out[:10]}...")
 
     if args.obs:
-        if eng.drift is not None and eng.drift.n_observed:
-            st = eng.drift.status()
-            print(f"drift monitor on {st['target']}: geomean "
-                  f"modeled/measured {st['geomean_ratio']:.3f} "
-                  f"({'in' if st['in_band'] else 'OUT OF'} band "
-                  f"{tuple(st['band'])}, {st['n_observed']} observations)")
         plan = eng.decode_plan or eng.block_plan
         for path in (args.obs_trace, args.trace):
             if path:

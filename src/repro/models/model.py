@@ -31,7 +31,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.core import hw
 from repro.core.ftl import InfeasibleError, graph
 from repro.core.ftl import registry as ftl_registry
 from repro.distributed.act_sharding import constrain
@@ -221,7 +220,12 @@ def plan_or_none(cfg, **kwargs):
     the planner's documented outcome: the config has no plannable block
     (pure recurrent stacks), or no tile assignment fits the target
     (``InfeasibleError``, logged).  Any other planning error propagates —
-    a model that should have a plan never silently runs without one."""
+    a model that should have a plan never silently runs without one.
+
+    The model's one way in to a block plan: ``forward`` and the serving
+    engine both plan through it, and ``registry.plan_block`` holds the
+    only cache (keyed on the resolved target, the autotune config and
+    the phase, among the shapes)."""
     if not graph.has_plannable_block(cfg):
         return None
     try:
@@ -231,41 +235,8 @@ def plan_or_none(cfg, **kwargs):
         return None
 
 
-@functools.lru_cache(maxsize=256)
-def _block_plan_cached(cfg, m: int, dtype: str, target, autotune):
-    if cfg.is_moe or cfg.ftl_mode == "off":
-        return None
-    return plan_or_none(cfg, m=m, dtype=dtype, target=target,
-                        autotune=autotune)
-
-
-def _block_plan(cfg, m: int, dtype: str, target=None, autotune=None):
-    """Cached per-(cfg, m, dtype, target, autotune) whole-block FTL plan,
-    or None.
-
-    The one plan every block of the forward pass executes through
-    (``registry.plan_block`` additionally caches per platform).  The
-    planning target is resolved *before* the cache lookup so changing the
-    default target (hw.set_default_target / FTL_TARGET) can never serve a
-    plan made for a different hierarchy — the Target hashes over its
-    full level description, so editing any level field (capacity,
-    bandwidth, ``buffer_depth``) is a new cache key (regression-pinned
-    in tests/test_objective.py).  ``autotune`` (a
-    :class:`repro.tune.AutotuneConfig`) is likewise part of the key:
-    a DES-tuned plan and the analytic plan for the same shapes never
-    alias (regression-pinned in tests/test_tune.py).  None — and the
-    hand-sequenced path — when there is nothing to plan:
-    ``ftl_mode='off'`` is the full escape hatch (run_block would pin the
-    baseline executors anyway, so skipping the solver at trace time gives
-    the identical compute graph for free), pure SSM stacks have no
-    plannable block, and MoE FFNs route (not a chain).
-    """
-    target = target if target is not None else hw.default_target()
-    return _block_plan_cached(cfg, m, dtype, target, autotune)
-
-
 # ---------------------------------------------------------------------------
-# serving plan cache: bucketed prefill shapes + phase-split plans
+# serving: the prefill bucket ladder
 # ---------------------------------------------------------------------------
 
 # The prefill bucket ladder: prompts are padded up to the next rung so the
@@ -286,37 +257,6 @@ def bucket_m(m: int, buckets: tuple[int, ...] = PREFILL_BUCKETS) -> int:
             return b
     raise ValueError(
         f"m={m} exceeds the largest prefill bucket {max(buckets)}")
-
-
-@functools.lru_cache(maxsize=512)
-def _serve_plan_cached(cfg, m: int, dtype: str, target, phase: str):
-    return plan_or_none(cfg, m=m, dtype=dtype, target=target, phase=phase)
-
-
-ftl_registry.register_plan_cache("model._block_plan_cached",
-                                 _block_plan_cached)
-ftl_registry.register_plan_cache("model._serve_plan_cached",
-                                 _serve_plan_cached)
-
-
-def serve_plan(cfg, *, m: int, dtype: str | None = None, target=None,
-               phase: str = "prefill",
-               buckets: tuple[int, ...] = PREFILL_BUCKETS):
-    """(bucketed m, BlockPlan-or-None) for one serving regime.
-
-    The plan cache is keyed ``(cfg, bucketed m, dtype, target, phase)``:
-    prefill shapes bucket through the ladder so every request in a bucket
-    reuses one plan; decode always plans at ``m=1`` through the same
-    partition DP — memory-bound, so it generally cuts differently than
-    prefill (pinned on ``rv32_npu`` in tests/test_serve.py).  Unlike
-    :func:`_block_plan` this does not gate on ``cfg.ftl_mode`` — serving
-    always wants the plan for reporting/qualification, and the executors
-    honor the mode at dispatch.  None when nothing is plannable (pure
-    SSM, MoE)."""
-    target = target if target is not None else hw.default_target()
-    dtype = dtype if dtype is not None else cfg.dtype
-    mb = 1 if phase == "decode" else bucket_m(m, buckets)
-    return mb, _serve_plan_cached(cfg, mb, dtype, target, phase)
 
 
 # ===========================================================================
@@ -401,7 +341,11 @@ def forward(cfg, params: Params, batch: dict[str, jax.Array]
     kinds, _, rem_kinds = _layer_split(cfg)
 
     x = constrain(_embed(cfg, params["embed"], tokens), "residual")
-    plan = _block_plan(cfg, s, cfg.dtype)
+    # ftl_mode='off' is the full escape hatch (run_block would pin the
+    # baseline executors anyway, so skipping the solver at trace time
+    # gives the same graph), and MoE FFNs route rather than chain
+    plan = (None if cfg.ftl_mode == "off" or cfg.is_moe
+            else plan_or_none(cfg, m=s, dtype=cfg.dtype))
     x, aux = _scan_layers(cfg, params["layers"], kinds, x,
                           positions=positions, ctx=ctx, plan=plan)
     for i, kind in enumerate(rem_kinds):

@@ -1,6 +1,6 @@
-"""Unified runtime telemetry: spans, metrics, exporters, drift.
+"""Unified runtime telemetry: spans, metrics, exporters.
 
-The observability layer the ISSUE-10 tentpole asks for.  Four pieces:
+The observability layer the ISSUE-10 tentpole asks for.  Three pieces:
 
 * :mod:`repro.obs.spans` — monotonic-clock span recorder on a
   preallocated ring buffer (nesting + thread id, no allocation on the
@@ -9,19 +9,15 @@ The observability layer the ISSUE-10 tentpole asks for.  Four pieces:
 * :mod:`repro.obs.metrics` — named counters / gauges / histograms on a
   process-global registry, always on, with pull-style collectors for
   surfaces that keep their own counters (the plan-cache ledger,
-  ``ServeEngine.plan_report()``).
+  ``ServeEngine.stats``).
 * :mod:`repro.obs.export` — the merged Perfetto timeline (live spans on
   pid 1 next to ``sim.to_chrome_trace``'s modeled/measured tracks on
   pid 0), Prometheus text exposition, JSON snapshot.
-* :mod:`repro.obs.drift` — online modeled-vs-measured drift: executed
-  segments become ``calib.Measurement`` rows with a rolling geomean
-  ratio per (segment, target), flagged when it leaves the PR-9 band.
 
 Importing this package never pulls jax — instrumented planner modules
 stay importable in jax-free tooling.
 """
-from . import drift, export, metrics, spans
-from .drift import DEFAULT_BAND, DriftMonitor
+from . import export, metrics, spans
 from .export import (merged_chrome_trace, metrics_snapshot,
                      prometheus_text, write_merged_trace,
                      write_prometheus)
@@ -32,7 +28,7 @@ from .spans import (Span, SpanRecorder, begin, disable, enable, enabled,
                     end, record, recorder, span, switch)
 
 __all__ = [
-    "spans", "metrics", "export", "drift",
+    "spans", "metrics", "export",
     # spans
     "Span", "SpanRecorder", "enable", "disable", "enabled", "recorder",
     "begin", "end", "switch", "record", "span",
@@ -42,6 +38,4 @@ __all__ = [
     # export
     "merged_chrome_trace", "write_merged_trace", "prometheus_text",
     "write_prometheus", "metrics_snapshot",
-    # drift
-    "DriftMonitor", "DEFAULT_BAND",
 ]

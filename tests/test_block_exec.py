@@ -146,19 +146,49 @@ def test_forward_plan_vs_handsequenced(arch, monkeypatch):
     cfg = _fp32(arch, ftl_mode="auto")
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     batch = {"tokens": jnp.arange(2 * 16).reshape(2, 16) % cfg.vocab_size}
-    assert M._block_plan(cfg, 16, cfg.dtype) is not None
+    assert M.plan_or_none(cfg, m=16, dtype=cfg.dtype) is not None
     y_plan, _ = M.forward(cfg, params, batch)
-    monkeypatch.setattr(M, "_block_plan", lambda *a, **k: None)
+    monkeypatch.setattr(M, "plan_or_none", lambda *a, **k: None)
     y_ref, _ = M.forward(cfg, params, batch)
     np.testing.assert_allclose(y_plan, y_ref, **TOL)
 
 
-def test_forward_skips_planning_when_ftl_off():
+def test_forward_skips_planning_when_ftl_off(monkeypatch):
     """ftl_mode='off' is the zero-cost escape hatch: no plan is built
     (no trace-time solver work) and the hand-sequenced path runs."""
     cfg = _fp32("llama3.2-3b")
     assert cfg.ftl_mode == "off"
-    assert M._block_plan(cfg, 16, cfg.dtype) is None
+
+    def no_planning(*a, **k):
+        raise AssertionError("forward planned under ftl_mode='off'")
+
+    monkeypatch.setattr(registry, "plan_block", no_planning)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    batch = {"tokens": jnp.arange(2 * 16).reshape(2, 16) % cfg.vocab_size}
+    y, _ = M.forward(cfg, params, batch)
+    assert np.isfinite(np.asarray(y)).all()
+
+
+@pytest.mark.parametrize("ftl_mode", ["off", "auto"])
+def test_forward_asks_for_a_plan_only_when_planning_is_on(ftl_mode,
+                                                          monkeypatch):
+    """The ftl_mode gate sits at forward's call site: under 'off' it
+    asks for no plan, under 'auto' for one, at m = the sequence
+    length."""
+    cfg = _fp32("llama3.2-3b", ftl_mode=ftl_mode)
+    asked = []
+    plan_or_none = M.plan_or_none
+
+    def spy(c, **kw):
+        asked.append(kw)
+        return plan_or_none(c, **kw)
+
+    monkeypatch.setattr(M, "plan_or_none", spy)
+    params = jax.eval_shape(M.init_params, cfg, jax.random.PRNGKey(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 24), jnp.int32)}
+    jax.eval_shape(lambda p, b: M.forward(cfg, p, b), params, batch)
+    want = [] if ftl_mode == "off" else [24]
+    assert [kw["m"] for kw in asked] == want
 
 
 def test_serve_engine_executes_block_plan():

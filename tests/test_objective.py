@@ -16,7 +16,7 @@ roofline model instead of trusting it:
 * per-level buffer depth: depth 1 on the cache-backed ``cpu_cache``
   reproduces the depth-2 plans where they were already feasible, a
   depth-3 VMEM level strictly shrinks the max feasible tile size, and
-  depth changes invalidate the model-level plan cache.
+  depth changes invalidate the block plan cache.
 """
 import dataclasses
 
@@ -446,19 +446,20 @@ class TestBufferDepth:
                                              buffer_depth=3)
 
     def test_model_plan_cache_invalidated_by_depth_change(self):
-        """models/model.py keys its per-block plan cache on the resolved
-        target; a buffer-depth change is a different machine and must
+        """The block plan cache keys on the resolved target; a
+        buffer-depth change is a different machine and must
         produce a distinct plan object (never a stale ×2-era plan)."""
         from repro.models import model as M
         cfg = dataclasses.replace(
             configs.get_config("llama3.2-3b").reduced(),
             dtype="float32", remat=False, ftl_mode="auto")
-        base = M._block_plan(cfg, 32, "float32", target=hw.TPU_V5E)
-        deep = M._block_plan(cfg, 32, "float32",
-                             target=hw.TPU_V5E.with_buffer_depth(3))
+        base = M.plan_or_none(cfg, m=32, dtype="float32",
+                              target=hw.TPU_V5E)
+        deep = M.plan_or_none(cfg, m=32, dtype="float32",
+                              target=hw.TPU_V5E.with_buffer_depth(3))
         assert base is not None and deep is not None
         assert deep is not base
         assert deep.target.fast.buffer_depth == 3
         # same depth resolves back to the same cached object
-        assert M._block_plan(cfg, 32, "float32",
-                             target=hw.TPU_V5E) is base
+        assert M.plan_or_none(cfg, m=32, dtype="float32",
+                              target=hw.TPU_V5E) is base

@@ -216,8 +216,10 @@ def test_decode_plan_differs_from_prefill_on_rv32_npu():
     RISC-V hierarchy — the split-plan tentpole, pinned."""
     cfg = configs.get_config("llama3.2-3b").reduced()
     tgt = hw.get_target("rv32_npu")
-    _, pre = M.serve_plan(cfg, m=64, target=tgt, phase="prefill")
-    _, dec = M.serve_plan(cfg, m=1, target=tgt, phase="decode")
+    pre = M.plan_or_none(cfg, m=64, dtype=cfg.dtype, target=tgt,
+                         phase="prefill")
+    dec = M.plan_or_none(cfg, m=1, dtype=cfg.dtype, target=tgt,
+                         phase="decode")
     assert pre is not None and dec is not None
     assert pre.phase == "prefill" and dec.phase == "decode"
     assert pre.m == 64 and dec.m == 1
@@ -225,10 +227,10 @@ def test_decode_plan_differs_from_prefill_on_rv32_npu():
 
 
 def test_serve_plan_cache_keys_bucket_ladder():
-    """serve_plan is keyed (cfg, bucketed m, dtype, target, phase):
+    """Serving plans are keyed (cfg, bucketed m, dtype, target, phase):
     requests inside one bucket share a plan object, bucket/phase/target
-    changes never serve a stale plan (mirrors the _block_plan target and
-    autotune key regressions)."""
+    changes never serve a stale plan (mirrors the forward plan's target
+    and autotune key regressions)."""
     cfg = configs.get_config("llama3.2-3b").reduced()
     assert M.bucket_m(1) == 8 and M.bucket_m(8) == 8
     assert M.bucket_m(9) == 16 and M.bucket_m(16) == 16
@@ -238,18 +240,22 @@ def test_serve_plan_cache_keys_bucket_ladder():
         M.bucket_m(M.PREFILL_BUCKETS[-1] + 1)
 
     tgt = hw.get_target("cpu_cache")
-    m10 = M.serve_plan(cfg, m=10, target=tgt, phase="prefill")
-    m16 = M.serve_plan(cfg, m=16, target=tgt, phase="prefill")
+
+    def plan(m, phase="prefill", target=tgt):
+        mb = 1 if phase == "decode" else M.bucket_m(m)
+        return mb, M.plan_or_none(cfg, m=mb, dtype=cfg.dtype,
+                                  target=target, phase=phase)
+
+    m10, m16 = plan(10), plan(16)
     assert m10[0] == m16[0] == 16
     assert m10[1] is m16[1]                     # same bucket -> same plan
-    m17 = M.serve_plan(cfg, m=17, target=tgt, phase="prefill")
+    m17 = plan(17)
     assert m17[0] == 32 and m17[1] is not m16[1]
     # decode is its own key at m=1 regardless of the requested m
-    d = M.serve_plan(cfg, m=16, target=tgt, phase="decode")
+    d = plan(16, phase="decode")
     assert d[0] == 1 and d[1] is not m16[1] and d[1].phase == "decode"
     # a different hierarchy never reuses the cpu_cache plan
-    other = M.serve_plan(cfg, m=16, target=hw.get_target("rv32_l1_l2"),
-                         phase="prefill")
+    other = plan(16, target=hw.get_target("rv32_l1_l2"))
     assert other[1] is not m16[1]
 
 
@@ -278,23 +284,27 @@ def test_decode_phase_disqualifies_pallas():
                                 m=1, phase="bogus")
 
 
-def test_zero_replans_and_both_phase_executors(setup):
-    """Steady-state decode never replans (100% plan-cache hits after
-    warmup) and the engine reports resolved executors for BOTH serving
-    regimes, mirroring what train logs for its one shape."""
+def test_zero_replans_and_both_phase_executors(setup, monkeypatch):
+    """Once built, the engine never plans again: with the planner made
+    to raise, prompts landing in several rungs of the ladder are traced,
+    compiled and served, and the engine still reports resolved executors
+    for BOTH serving regimes, mirroring what train logs for its one
+    shape."""
     cfg, params = setup
     rng = np.random.default_rng(8)
     reqs = [Request(i, rng.integers(2, cfg.vocab_size, size=4 + 5 * i)
                     .astype(np.int32), 4) for i in range(6)]
     eng = ServeEngine(cfg, params, batch_slots=3, max_seq=32, eos_id=-1)
-    eng.warmup_compile()
-    warm_misses = eng.plans.counters()["misses"]
-    eng.run(reqs, {})
-    after = eng.plans.counters()
+
+    def no_planning(*a, **k):
+        raise AssertionError("the engine planned after construction")
+
+    monkeypatch.setattr(ftl_registry, "plan_block", no_planning)
+    done = eng.run(reqs, {})
+    assert sorted(r.rid for r in done) == list(range(6))
+    assert len({r.bucket for r in done}) >= 2
+    assert set(eng.prefill_plans) == set(eng.buckets)
     assert eng.stats["replans"] == 0
-    assert after["misses"] == warm_misses
-    assert after["misses_after_warmup"] == 0
-    assert after["hits"] > 0
 
     report = eng.plan_report()
     for phase in ("prefill", "decode"):
@@ -305,37 +315,20 @@ def test_zero_replans_and_both_phase_executors(setup):
     assert report["decode"]["m"] == 1
 
 
-def test_clear_plan_caches_resets_serve_counters(setup):
-    """Regression: ``registry.clear_plan_caches()`` used to drop the 13
-    lru caches but leave the engine's PlanCache counters and replan stat
-    standing, so ``plan_report`` claimed reuse of plans the clear had
-    invalidated.  A clear must reset hits/misses/warmth/replans with the
-    caches — and the engine must still serve afterwards (replanning,
-    and saying so)."""
+@pytest.mark.parametrize("phase", ["prefill", "decode"])
+def test_engine_plans_are_the_planner_cache_entries(setup, phase):
+    """The engine holds the planner's own cached plans: each phase's
+    plan *is* what ``M.plan_or_none`` returns for the same key, so one
+    cache serves the engine and everyone else."""
     cfg, params = setup
-    rng = np.random.default_rng(9)
     eng = ServeEngine(cfg, params, batch_slots=1, max_seq=32, eos_id=-1)
-    assert eng.plans.counters()["plans"] > 0    # warmed at construction
-    assert eng.plans.warmed
-
-    ftl_registry.clear_plan_caches()
-    c = eng.plans.counters()
-    assert c == {"plans": 0, "hits": 0, "misses": 0,
-                 "misses_after_warmup": 0}
-    assert not eng.plans.warmed
-    assert eng.stats["replans"] == 0
-    # the ledger itself reset too
-    for stats in ftl_registry.plan_cache_stats().values():
-        assert stats["hits"] == 0 and stats["misses"] == 0
-
-    # serving after a clear replans cleanly: fresh plan objects, honest
-    # miss counters (not misses_after_warmup — warmth was reset too)
-    prompt = rng.integers(2, cfg.vocab_size, size=6).astype(np.int32)
-    done = eng.run([Request(0, prompt, 3)], {})
-    assert len(done) == 1 and len(done[0].out) == 3
-    c = eng.plans.counters()
-    assert c["plans"] > 0 and c["misses"] > 0
-    assert c["misses_after_warmup"] == 0
+    plans = (eng.prefill_plans if phase == "prefill"
+             else {1: eng.decode_plan})
+    assert eng.block_plan is eng.prefill_plans[eng.buckets[-1]]
+    for m, plan in plans.items():
+        assert plan is not None and plan.phase == phase and plan.m == m
+        assert plan is M.plan_or_none(cfg, m=m, dtype=cfg.dtype,
+                                      target=eng.target, phase=phase)
 
 
 # ---------------------------------------------------------------------------
@@ -377,10 +370,10 @@ def _one_paged_step(cfg, params, prompt_lens: dict[int, int], slots: int,
     woff = np.zeros(slots, np.int32)
     for i, n in prompt_lens.items():
         prompt = rng.integers(2, cfg.vocab_size, size=n).astype(np.int32)
-        bucket, plan = eng.plans.get(n, "prefill")
+        bucket = M.bucket_m(n, eng.buckets)
         padded = np.zeros(bucket, np.int32)
         padded[:n] = prompt
-        _, cache1 = eng.prefill_fn(bucket, plan)(
+        _, cache1 = eng.prefill_fn(bucket, eng.prefill_plans[bucket])(
             params, {"tokens": jnp.asarray(padded)[None]}, jnp.int32(n - 1))
         eng.kv.write_prefill(i, cache1, bucket)
         dense = jax.tree.map(
@@ -446,8 +439,9 @@ def test_paged_inplace_step_writes_only_the_new_tokens(paged_case):
 
 
 def test_paged_engine_counts_inplace_steps(setup):
-    """Every decode step of a paged engine runs the in-place step; the
-    dense per-slot engine runs none."""
+    """A paged engine counts a kernel step for every decode step whose
+    attention ran the paged kernel (none here: the jnp gather on the
+    CPU); the dense per-slot engine does not page."""
     cfg, params = setup
     rng = np.random.default_rng(13)
     reqs = lambda: [Request(i, rng.integers(2, cfg.vocab_size, size=5 + i)  # noqa: E731
@@ -455,12 +449,13 @@ def test_paged_engine_counts_inplace_steps(setup):
     eng = ServeEngine(cfg, params, batch_slots=2, max_seq=32, eos_id=-1)
     eng.run(reqs(), {})
     assert eng.paged and eng.stats["decode_steps"] > 0
-    assert eng.stats["paged_inplace_steps"] == eng.stats["decode_steps"]
+    assert eng.stats["paged_kernel_steps"] == (
+        eng.stats["decode_steps"] if eng.paged_kernel else 0)
     eng_d = ServeEngine(cfg, params, batch_slots=2, max_seq=32,
                         paged=False, eos_id=-1)
     eng_d.run(reqs(), {})
     assert eng_d.stats["decode_steps"] > 0
-    assert eng_d.stats["paged_inplace_steps"] == 0
+    assert eng_d.paged is False
 
 
 # ---------------------------------------------------------------------------

@@ -319,7 +319,7 @@ def test_full_mlp_segment_on_rv32_beats_unfused_when_feasible():
 
 
 # ---------------------------------------------------------------------------
-# regression: the model-level plan cache is keyed by target
+# regression: the block plan cache is keyed by target
 # ---------------------------------------------------------------------------
 
 def test_model_block_plan_cache_keys_target():
@@ -328,26 +328,26 @@ def test_model_block_plan_cache_keys_target():
     from repro.models import model as M
     cfg = dataclasses.replace(configs.get_config("llama3.2-3b").reduced(),
                               dtype="float32", remat=False, ftl_mode="auto")
-    plan_default = M._block_plan(cfg, 32, "float32")
+    plan_default = M.plan_or_none(cfg, m=32, dtype="float32")
     assert plan_default is not None
     assert plan_default.target == hw.default_target()
     # explicit target: distinct plan object for a distinct machine
-    plan_rv32 = M._block_plan(cfg, 32, "float32",
-                              target=hw.get_target("rv32_l1_l2"))
+    plan_rv32 = M.plan_or_none(cfg, m=32, dtype="float32",
+                               target=hw.get_target("rv32_l1_l2"))
     assert plan_rv32 is not None
     assert plan_rv32.target.name == "rv32_l1_l2"
     assert plan_rv32 is not plan_default
     # default-target switch reaches the cache key too
     try:
         hw.set_default_target("rv32_l1_l2")
-        plan_switched = M._block_plan(cfg, 32, "float32")
+        plan_switched = M.plan_or_none(cfg, m=32, dtype="float32")
         assert plan_switched is not None
         assert plan_switched.target.name == "rv32_l1_l2"
         assert plan_switched is not plan_default
     finally:
         hw.set_default_target(None)
     # and with the default restored, the original plan is served again
-    assert M._block_plan(cfg, 32, "float32") is plan_default
+    assert M.plan_or_none(cfg, m=32, dtype="float32") is plan_default
 
 
 # ---------------------------------------------------------------------------

@@ -198,10 +198,11 @@ def test_model_block_plan_cache_keys_autotune():
     from repro.models import model as M
     cfg, _ = _zoo_block()
     t = hw.TPU_V5E
-    plan_plain = M._block_plan(cfg, 32, "float32", target=t)
+    plan_plain = M.plan_or_none(cfg, m=32, dtype="float32", target=t)
     assert plan_plain is not None
     assert plan_plain.tune is None
-    plan_tuned = M._block_plan(cfg, 32, "float32", target=t, autotune=FAST)
+    plan_tuned = registry.plan_block(cfg, m=32, dtype="float32", target=t,
+                                     autotune=FAST)
     assert plan_tuned is not None
     assert plan_tuned is not plan_plain
     assert plan_tuned.tune is not None
@@ -210,10 +211,12 @@ def test_model_block_plan_cache_keys_autotune():
             <= hw.round_time(plan_tuned.tune.baseline_sim_runtime_s))
     # a different tuning config is a different key too
     other = dataclasses.replace(FAST, max_sims=32)
-    plan_other = M._block_plan(cfg, 32, "float32", target=t, autotune=other)
+    plan_other = registry.plan_block(cfg, m=32, dtype="float32", target=t,
+                                     autotune=other)
     assert plan_other is not plan_tuned
     # and the untuned entry is still served untouched
-    assert M._block_plan(cfg, 32, "float32", target=t) is plan_plain
+    assert M.plan_or_none(cfg, m=32, dtype="float32",
+                          target=t) is plan_plain
 
 
 def test_registry_plan_block_binds_tuned_chain():
